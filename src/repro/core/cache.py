@@ -458,7 +458,9 @@ class CachedEmbeddingBagCollection:
         every subsequent exchange donates them to XLA so the swap updates
         rows in place instead of moving the whole tier (the caller's arrays
         stay valid; arrays handed out by `materialize` may be donated again
-        by later flushes)."""
+        by later flushes). A host (numpy) `mega` is transferred, so the
+        state's capacity array is the only device copy of the table — how
+        the serve launcher builds it (launch/serve.py)."""
         r, d = mega.shape
         assert r == self.ebc.plan.total_rows, (r, self.ebc.plan.total_rows)
         c = self.cache_rows

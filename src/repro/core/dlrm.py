@@ -71,32 +71,35 @@ def _mlp_apply(layers, x, dtype):
 
 
 def dlrm_forward_dense(params: dict, dense_x: jax.Array, pooled: jax.Array,
-                       cfg: DLRMConfig, interpret: bool = False) -> jax.Array:
+                       cfg: DLRMConfig, interpret: bool = False,
+                       use_kernel: bool | None = None) -> jax.Array:
     """Everything downstream of the embedding lookup (autodiff runs here).
 
     dense_x: (B, n_dense); pooled: (B, F, d). Returns (B,) logits.
+    `use_kernel=False` takes the jnp reference interaction on any backend.
     """
     dtype = jnp.float32 if cfg.compute_dtype == "float32" else jnp.bfloat16
     bot = _mlp_apply(params["bottom"], dense_x.astype(dtype), dtype)
     top_in = interact(bot, pooled.astype(dtype), cfg.interaction,
-                      interpret=interpret)
+                      use_kernel=use_kernel, interpret=interpret)
     logit = _mlp_apply(params["top"], top_in, dtype)
     return logit[..., 0].astype(jnp.float32)
 
 
-def _lookup(params, batch, cfg, ebc, rules):
+def _lookup(params, batch, cfg, ebc, rules, use_kernel=None):
     if cfg.lookup_impl == "psum":
         from repro.nn.sharding import _live_mesh
         mesh = _live_mesh()
         if mesh is not None:
-            return ebc.lookup_pooled_psum(params["emb"], batch["idx"], mesh)
+            return ebc.lookup_pooled_psum(params["emb"], batch["idx"], mesh,
+                                          use_kernel=use_kernel)
     # a batch-attached bucketing plan (data.sparse_plan_hook, or the cached
     # steps' slot-relabelled copy) dedups the forward gather — the plan is
     # built once per batch and shared with the fused backward and the
     # cached tiers' miss planning (docs/embedding_forward.md)
     from repro.kernels.sparse_plan import plan_from_batch
     return ebc.lookup(params["emb"], batch["idx"], rules,
-                      plan=plan_from_batch(batch))
+                      plan=plan_from_batch(batch), use_kernel=use_kernel)
 
 
 def dlrm_forward(params: dict, batch: dict, cfg: DLRMConfig,
@@ -136,7 +139,7 @@ def normalized_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 def dlrm_grads(params: dict, batch: dict, cfg: DLRMConfig,
                ebc: EmbeddingBagCollection, interpret: bool = False,
-               rules=None
+               rules=None, use_kernel: bool | None = None
                ) -> tuple[jax.Array, dict, tuple[jax.Array, jax.Array]]:
     """Returns (loss, dense_grads, (idx (B,F,L), pooled_grads (B,F,d))).
 
@@ -144,13 +147,13 @@ def dlrm_grads(params: dict, batch: dict, cfg: DLRMConfig,
     pooled embeddings as a leaf input, and sum-pooling lets every valid
     lookup slot inherit its bag's gradient.
     """
-    pooled = _lookup(params, batch, cfg, ebc, rules)
+    pooled = _lookup(params, batch, cfg, ebc, rules, use_kernel)
     dense_params = {"bottom": params["bottom"], "top": params["top"]}
 
     def loss_fn(dp, pl_):
         """BCE loss over the dense tower, pooled embeddings as a leaf."""
         logits = dlrm_forward_dense({**dp, "emb": None}, batch["dense"],
-                                    pl_, cfg, interpret)
+                                    pl_, cfg, interpret, use_kernel)
         return _bce(logits, batch["label"])
 
     loss, (g_dense, g_pooled) = jax.value_and_grad(

@@ -27,7 +27,33 @@ import numpy as np
 from repro.configs.base import DLRMConfig
 from repro.core.placement import PlacementPlan, plan_placement
 from repro.kernels import ops
+from repro.kernels.cache_ops import gather_rows
+from repro.kernels.sparse_plan import build_sparse_plan
 from repro.nn.params import ParamSpec
+
+
+def _row_taker(mega: jax.Array, idx: jax.Array, plan, use_kernel):
+    """take(flat_rows) -> mega rows for the lookups of `idx` (B, F, L).
+
+    Without a plan, a direct gather per lookup slot. With one, the table
+    is read once per unique row into a compact slab that every slot then
+    reads through an index-only searchsorted remap. On TPU a plan is
+    always built and the compact slab is read by the row-move kernel
+    (kernels/cache_ops.gather_rows): an XLA gather from the table would
+    first relayout all of it into a padded copy twice its size."""
+    kernel = ops.use_pallas(use_kernel)
+    if plan is None and kernel:
+        plan = build_sparse_plan(idx)
+    if plan is None:
+        return lambda flat: jnp.take(mega, flat, axis=0)
+    if kernel:
+        compact = gather_rows(mega, plan.unique_rows, use_kernel=True)
+    else:
+        compact = jnp.take(mega, jnp.maximum(plan.unique_rows, 0), axis=0)
+    sent = jnp.where(plan.unique_rows >= 0, plan.unique_rows,
+                     jnp.iinfo(jnp.int32).max)
+    return lambda flat: jnp.take(compact, jnp.searchsorted(sent, flat),
+                                 axis=0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +114,7 @@ class EmbeddingBagCollection:
     # -- lookup ------------------------------------------------------------
 
     def lookup(self, params: dict, idx: jax.Array, rules=None,
-               plan=None) -> jax.Array:
+               plan=None, use_kernel: bool | None = None) -> jax.Array:
         """idx: (B, F, L) offset global rows, -1 pads. Returns (B, F, d)
         sum-pooled embeddings. Pure-jnp global-semantics path: under pjit the
         gather from the model-sharded mega table lowers to local gathers +
@@ -103,25 +129,13 @@ class EmbeddingBagCollection:
         is the SAME code either way, so the planned path is BIT-EXACT vs
         the plan-less one (asserted in tests/test_dedup_forward.py) — the
         forward half of the plan-once-used-thrice contract
-        (docs/embedding_forward.md)."""
+        (docs/embedding_forward.md). On TPU the table rows are read by the
+        row-move kernel (`use_kernel=False` keeps the XLA gather; see
+        `_row_taker`)."""
         from repro.nn.sharding import shard_activation
         mega = params["mega"]
         b, f, lk = idx.shape
-
-        if plan is None:
-            def take(flat):                  # flat: (n,) clipped global rows
-                """Direct mega-table gather."""
-                return jnp.take(mega, flat, axis=0)
-        else:
-            compact = jnp.take(mega, jnp.maximum(plan.unique_rows, 0),
-                               axis=0)       # the ONLY mega-table gather
-            sent = jnp.where(plan.unique_rows >= 0, plan.unique_rows,
-                             jnp.iinfo(jnp.int32).max)
-
-            def take(flat):
-                """Gather via the plan's deduplicated compact slab."""
-                return jnp.take(compact, jnp.searchsorted(sent, flat),
-                                axis=0)
+        take = _row_taker(mega, idx, plan, use_kernel)
 
         def pool_one(_, idx_f):
             """Pool one feature's bags; scanned over the feature axis."""
@@ -148,7 +162,8 @@ class EmbeddingBagCollection:
                                 rules or {})
 
     def lookup_pooled_psum(self, params: dict, idx: jax.Array,
-                           mesh, model_axis: str = "model") -> jax.Array:
+                           mesh, model_axis: str = "model",
+                           use_kernel: bool | None = None) -> jax.Array:
         """shard_map lookup with PS-SIDE POOLING: each model shard pools its
         local rows per bag, then a psum of the (B, F, d) POOLED tensor
         crosses shards — instead of the naive gather whose cross-shard
@@ -157,8 +172,6 @@ class EmbeddingBagCollection:
         reason). Requires plan.pspec == P(model_axis, None) and the batch
         sharded over the remaining axes."""
         from jax.sharding import PartitionSpec as P
-
-        from repro.compat import shard_map
         assert self.plan.pspec == P(model_axis, None), self.plan.pspec
         batch_axes = tuple(a for a in mesh.axis_names if a != model_axis)
         rows_local = self.plan.total_rows // mesh.shape[model_axis]
@@ -173,17 +186,20 @@ class EmbeddingBagCollection:
                             idx_local - lo, -1)
             b, f, lk = loc.shape
             valid = loc >= 0
-            rows = jnp.take(mega_shard, jnp.maximum(loc, 0).reshape(-1),
-                            axis=0).reshape(b, f, lk, d)
+            take = _row_taker(mega_shard, loc, None, use_kernel)
+            rows = take(jnp.maximum(loc, 0).reshape(-1)).reshape(b, f, lk, d)
             rows = jnp.where(valid[..., None], rows.astype(jnp.float32),
                              0.0)
             pooled = rows.sum(axis=2)          # POOL BEFORE the collective
             return jax.lax.psum(pooled, model_axis)
 
-        return shard_map(
+        return jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(model_axis, None), P(batch_axes, None, None)),
             out_specs=P(batch_axes, None, None),
+            # the TPU row gather is a pallas_call, whose outputs carry no
+            # varying-axes metadata
+            check_vma=False,
         )(params["mega"], idx).astype(params["mega"].dtype)
 
     def lookup_local(self, mega_shard: jax.Array, idx: jax.Array,

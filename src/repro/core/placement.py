@@ -46,6 +46,12 @@ from jax.sharding import PartitionSpec as P
 #: row-wise AdaGrad accumulator (fp32) + LFU frequency score (fp32)
 CACHED_ROW_META_BYTES = 8
 
+#: every mega-table, per-shard and device-cache height is a multiple of
+#: this: the TPU kernels move rows as lanes of 128-row tiles of the table's
+#: (d, rows) view (kernels/sparse_update.py), which tiles such heights
+#: exactly and in place
+ROW_TILE = 128
+
 
 @dataclasses.dataclass(frozen=True)
 class PlacementPlan:
@@ -129,6 +135,7 @@ def plan_placement(hash_sizes: Sequence[int],
 
     if strategy == "replicated":
         offsets, rows = _contiguous(hash_sizes, pad_mult=8)
+        rows = _round_up(rows, ROW_TILE)
         return PlacementPlan(strategy, offsets, rows, P(None, None), None,
                              n_shards,
                              bytes_per_shard=(total_bytes,) * 1,
@@ -136,7 +143,7 @@ def plan_placement(hash_sizes: Sequence[int],
 
     if strategy == "row_wise":
         offsets, rows = _contiguous(hash_sizes, pad_mult=8)
-        rows = _round_up(rows, n_shards * 8)
+        rows = _round_up(rows, n_shards * ROW_TILE)
         per = rows // n_shards * embed_dim * itemsize
         pspec = P(model_axis, None)
         shards = n_shards
@@ -145,7 +152,7 @@ def plan_placement(hash_sizes: Sequence[int],
             # a single Big Basin cannot hold the tables): spread rows over
             # the full pod — pooled HBM is the Zion 2 TB tier (DESIGN 2)
             shards = n_shards * second_axis_size
-            rows = _round_up(rows, shards * 8)
+            rows = _round_up(rows, shards * ROW_TILE)
             per = rows // shards * embed_dim * itemsize
             pspec = P((model_axis, second_axis), None)
         return PlacementPlan(strategy, offsets, rows, pspec,
@@ -165,6 +172,7 @@ def plan_placement(hash_sizes: Sequence[int],
                 f"column_wise needs embed_dim divisible by n_shards, got "
                 f"{embed_dim} % {n_shards}; pad the dim or drop shards")
         offsets, rows = _contiguous(hash_sizes, pad_mult=8)
+        rows = _round_up(rows, ROW_TILE)
         per = rows * embed_dim // n_shards * itemsize
         return PlacementPlan(strategy, offsets, rows, P(None, model_axis),
                              None, n_shards,
@@ -188,11 +196,12 @@ def plan_placement(hash_sizes: Sequence[int],
         # payload + per-row AdaGrad accumulator + LFU score fit the
         # per-chip budget.
         offsets, rows = _contiguous(hash_sizes, pad_mult=8)
-        rows = _round_up(rows, max(8, capacity_shards * 8))
+        rows = _round_up(rows, capacity_shards * ROW_TILE)
         shard_rows = rows // capacity_shards
         row_bytes = embed_dim * itemsize + CACHED_ROW_META_BYTES
         cache_rows = int(hbm_budget_bytes // row_bytes)
-        cache_rows = max(8, min(cache_rows // 8 * 8, rows))
+        cache_rows = max(ROW_TILE,
+                         min(cache_rows // ROW_TILE * ROW_TILE, rows))
         pspec = P(None, None) if capacity_shards == 1 \
             else P(second_axis, None)
         per_host = cache_rows * row_bytes + (
@@ -354,7 +363,7 @@ def _table_wise(hash_sizes, loads, embed_dim, n_shards, budget, itemsize,
     # rows per shard = max shard allocation, padded so shards align
     rows_of = [_round_up(h, 8) for h in hash_sizes]
     shard_rows = max(sum(rows_of[t] for t in ts) for ts in shard_tables)
-    shard_rows = _round_up(max(shard_rows, 8), 8)
+    shard_rows = _round_up(max(shard_rows, ROW_TILE), ROW_TILE)
     offsets = [0] * n
     for s, ts in enumerate(shard_tables):
         off = s * shard_rows

@@ -3,20 +3,17 @@
 The paper (section III-A.2) identifies irregular embedding-vector access as the
 throughput limiter of recommendation training, and section VII notes prior
 near-memory accelerators are "not optimized for gradient aggregation". The
-three kernels here cover exactly that path:
+kernels here cover exactly that path:
 
   embedding_bag    fused multi-hot gather + pooling (fwd) — the EMB lookup,
                    legacy one-row-read-per-slot AND the plan-driven dedup'd
                    design (each unique row leaves HBM once per batch)
   dot_interaction  pairwise-dot feature interaction (section III-A.3), MXU-shaped
-  rowwise_adagrad  deduplicated sparse gradient aggregation + row-wise
-                   AdaGrad apply — the EMB backward/update (legacy two-pass)
-  sparse_update    fused bag-gradient gather + aggregation + row-wise
-                   AdaGrad over the sparse_plan.py CSR bucketing — the
-                   EMB backward/update without per-lookup gradients
-  cache_ops        capacity<->cache row exchange (eviction-writeback +
-                   fetch-on-miss) with fused LFU counter updates — the
-                   swap engine of the cached embedding tier (core/cache.py)
+  sparse_update    row-wise AdaGrad applied in place to a batch's unique
+                   rows (gradients aggregated by ref.bag_grad_sums over
+                   the sparse_plan.py CSR bucketing) — the EMB update
+  cache_ops        one row-move kernel behind the cached tier's exchange,
+                   fetch and commit, and the TPU embedding gather
   flash_attention  causal streaming attention with static triangle
                    skipping — the prefill_32k hot spot of the LM family
 

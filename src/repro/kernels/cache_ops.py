@@ -1,23 +1,31 @@
-"""Pallas TPU kernel: batched capacity<->cache row exchange + LFU counters.
+"""Pallas TPU kernel: batched capacity<->cache row moves for the cached
+embedding tier.
 
 The cached embedding tier (core/cache.py) keeps the mega table in a slow
-"capacity" tier (host-resident / pooled-HBM) and a fixed-size hot-row cache
-on device. Each step the manager emits a per-slot WORKLIST: slot i may first
-write its dirty victim row back to capacity (eviction-writeback) and then be
-refilled from a missed capacity row (fetch-on-miss), seeding the slot's LFU
-score. This kernel executes that worklist as an explicitly scheduled DMA
-pipeline — the TPU analogue of the UVM/CacheEmbedding swap-in/swap-out path —
-moving the embedding row AND its row-wise AdaGrad accumulator together so an
-evicted row can resume training after a later re-fetch.
+"capacity" array and a fixed-size hot-row cache on device. Each step the
+manager emits a per-slot WORKLIST: slot i may first write its dirty victim
+row back to capacity (eviction-writeback) and then be refilled from a
+missed capacity row (fetch-on-miss). Every operation here — the fused
+`cache_exchange`, the split async `cache_fetch` / `cache_fetch_chunked` /
+`cache_commit` — is one or two passes of ONE kernel, `move_rows`: copy
+row src_rows[i] of one table into row dst_rows[i] of another, in place.
+The small per-row side arrays (row-wise AdaGrad accumulators, LFU scores)
+move with XLA gathers/scatters on their 1-D arrays.
 
-Grid step i = worklist entry i; `pl.when` guards skip -1 entries, so one
-lowered kernel serves any hit/miss pattern. Rows ride HBM->VMEM->HBM through
-a (1, D) scratch; the accumulator and LFU scalar through (1, 1) scratches.
+Layout. Tables are (rows, d) with d=64 in production; XLA keeps such an
+array with the row axis minor, and a (1, 64) row slice is not DMA-able
+under the (8, 128) tiling. `move_rows` therefore works on the free
+transposed views (d, rows): a row is one lane of a (d, 128) tile. Per
+entry the source tile is DMA'd into VMEM (skipped when it is already
+resident), the column is rotated onto the destination lane, and the
+destination tile is merged under a lane mask and written back when the
+walk leaves it. Contiguous rows (a chunk-granular fetch, a sorted miss
+list) therefore share one tile DMA. Heights that are multiples of 128 (every
+placement plan's, core/placement.py) move in place with no copy.
 
-The `cache_exchange` / `lfu_touch` wrappers dispatch: Pallas kernel on TPU
-(or `interpret=True` for tests), pure-jnp oracle (kernels/ref.py) otherwise.
-D is padded to the 128-lane width here; real deployments keep D lane-aligned
-so the pad is a no-op.
+The worklist rides in SMEM one `ROW_BLOCK` block per grid step, never
+whole. The wrappers dispatch: the kernel on TPU (or `interpret=True` for
+tests), the pure-jnp oracle (kernels/ref.py) otherwise.
 """
 from __future__ import annotations
 
@@ -27,430 +35,203 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.tpu import MemorySpace, SemaphoreType
 
 from repro.kernels import ref
-from repro.kernels.compat import MemorySpace, SemaphoreType
-
-LANE = 128
-
-
-def _use_pallas(force: bool | None) -> bool:
-    if force is not None:
-        return force
-    return jax.default_backend() == "tpu"
+from repro.kernels.ops import use_pallas
+from repro.kernels.sparse_update import LANE, ROW_BLOCK, _pad_axis
 
 
-def _pad_lane(x: jax.Array) -> jax.Array:
-    pad = (-x.shape[1]) % LANE
-    if pad == 0:
-        return x
-    return jnp.pad(x, ((0, 0), (0, pad)))
+def _move_kernel(src_rows_ref, dst_rows_ref, src_ref, dst_in, dst_out,
+                 src_tile, dst_tile, cur, sems):
+    """One grid step: ROW_BLOCK entries; entry j copies src column
+    src_rows_ref[j] into dst column dst_rows_ref[j] (either -1 = skip).
+
+    src_rows/dst_rows: (ROW_BLOCK,) SMEM; src_ref: (d, R) HBM read-only;
+    dst_in/dst_out: (d, C) HBM aliased; src_tile/dst_tile: (d, 128) VMEM;
+    cur: (2,) SMEM, the resident source / destination tile (-1 = none)."""
+    del dst_in                         # aliased: read through dst_out
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+
+    def tile_dma(hbm_ref, tile, t, to_hbm, sem):
+        """Move tile t between HBM and a VMEM tile (blocking)."""
+        hbm = hbm_ref.at[:, pl.ds(pl.multiple_of(t * LANE, LANE), LANE)]
+        src, dst = (tile, hbm) if to_hbm else (hbm, tile)
+        cp = pltpu.make_async_copy(src, dst, sem)
+        cp.start()
+        cp.wait()
+
+    cur[0] = -1
+    cur[1] = -1
+
+    def body(j, carry):
+        s = src_rows_ref[j]
+        t = dst_rows_ref[j]
+
+        @pl.when((s >= 0) & (t >= 0))
+        def _():
+            @pl.when(s // LANE != cur[0])
+            def _():
+                tile_dma(src_ref, src_tile, s // LANE, False, sems.at[0])
+                cur[0] = s // LANE
+
+            @pl.when(t // LANE != cur[1])
+            def _():
+                @pl.when(cur[1] >= 0)
+                def _():
+                    tile_dma(dst_out, dst_tile, cur[1], True, sems.at[1])
+                tile_dma(dst_out, dst_tile, t // LANE, False, sems.at[1])
+                cur[1] = t // LANE
+
+            shift = (t % LANE - s % LANE + LANE) % LANE
+            col = pltpu.roll(src_tile[...], shift, 1)
+            dst_tile[...] = jnp.where(lane == t % LANE, col, dst_tile[...])
+
+        return carry
+
+    jax.lax.fori_loop(0, ROW_BLOCK, body, 0)
+
+    @pl.when(cur[1] >= 0)
+    def _():
+        tile_dma(dst_out, dst_tile, cur[1], True, sems.at[1])
 
 
-def _exchange_kernel(slots_ref, evict_ref, fetch_ref, counts_ref,
-                     capacity_ref, cache_ref, cap_acc_ref, cache_acc_ref,
-                     freq_ref, capacity_out, cache_out, cap_acc_out,
-                     cache_acc_out, freq_out, row_vmem, acc_vmem, frq_vmem,
-                     sems):
-    """Grid step i executes worklist entry i (see module docstring).
-
-    slots/evict/fetch/counts: (N,) SMEM scalar-prefetch; capacity/(R, D),
-    cache/(C, D), cap_acc/(R, 1), cache_acc/(C, 1), freq/(C, 1) all HBM and
-    io-aliased in->out; row_vmem: (1, D); acc_vmem/frq_vmem: (1, 1)."""
-    i = pl.program_id(0)
-    s = slots_ref[i]
-    ev = evict_ref[i]
-    ft = fetch_ref[i]
-
-    @pl.when((s >= 0) & (ev >= 0))
-    def _writeback():
-        cp_r = pltpu.make_async_copy(cache_ref.at[pl.ds(s, 1)], row_vmem,
-                                     sems.at[0])
-        cp_a = pltpu.make_async_copy(cache_acc_ref.at[pl.ds(s, 1)], acc_vmem,
-                                     sems.at[1])
-        cp_r.start()
-        cp_a.start()
-        cp_r.wait()
-        cp_a.wait()
-        cp_wr = pltpu.make_async_copy(row_vmem, capacity_out.at[pl.ds(ev, 1)],
-                                      sems.at[0])
-        cp_wa = pltpu.make_async_copy(acc_vmem, cap_acc_out.at[pl.ds(ev, 1)],
-                                      sems.at[1])
-        cp_wr.start()
-        cp_wa.start()
-        cp_wr.wait()
-        cp_wa.wait()
-
-    @pl.when((s >= 0) & (ft >= 0))
-    def _fetch():
-        cp_r = pltpu.make_async_copy(capacity_ref.at[pl.ds(ft, 1)], row_vmem,
-                                     sems.at[0])
-        cp_a = pltpu.make_async_copy(cap_acc_ref.at[pl.ds(ft, 1)], acc_vmem,
-                                     sems.at[1])
-        cp_r.start()
-        cp_a.start()
-        cp_r.wait()
-        cp_a.wait()
-        frq_vmem[...] = jnp.full((1, 1), counts_ref[i], jnp.float32)
-        cp_wr = pltpu.make_async_copy(row_vmem, cache_out.at[pl.ds(s, 1)],
-                                      sems.at[0])
-        cp_wa = pltpu.make_async_copy(acc_vmem, cache_acc_out.at[pl.ds(s, 1)],
-                                      sems.at[1])
-        cp_wf = pltpu.make_async_copy(frq_vmem, freq_out.at[pl.ds(s, 1)],
-                                      sems.at[2])
-        cp_wr.start()
-        cp_wa.start()
-        cp_wf.start()
-        cp_wr.wait()
-        cp_wa.wait()
-        cp_wf.wait()
-
-
-# only the (·, D) payloads are donated: the 1-D accum/freq args are
-# reshaped to (·, 1) before the pallas_call, so their input buffers cannot
-# alias the outputs anyway (and they are 64x smaller than the payload)
-@functools.partial(jax.jit, static_argnames=("interpret",),
-                   donate_argnums=(0, 1))
-def cache_exchange_kernel(capacity: jax.Array, cache: jax.Array,
-                          cap_accum: jax.Array, cache_accum: jax.Array,
-                          freq: jax.Array, slots: jax.Array,
-                          evict_rows: jax.Array, fetch_rows: jax.Array,
-                          counts: jax.Array, interpret: bool = False):
-    """capacity: (R, D), cache: (C, D) with D % 128 == 0; cap_accum: (R, 1),
-    cache_accum: (C, 1), freq: (C, 1) fp32; worklist slots/evict_rows/
-    fetch_rows: (N,) int32 (-1 = skip); counts: (N,) fp32 LFU seeds.
-    Returns the five arrays updated in place (io aliasing)."""
-    r, d = capacity.shape
-    c = cache.shape[0]
-    n = slots.shape[0]
+def move_rows_kernel(src_t: jax.Array, dst_t: jax.Array,
+                     src_rows: jax.Array, dst_rows: jax.Array,
+                     interpret: bool = False) -> jax.Array:
+    """src_t: (d, R), dst_t: (d, C) rows-on-lanes views with R, C % 128 ==
+    0 and one dtype; src_rows/dst_rows: (N,) int32 with N % ROW_BLOCK == 0
+    (-1 = skip). Destination rows must be distinct. Returns dst_t with
+    dst_t[:, dst_rows[i]] = src_t[:, src_rows[i]], updated in place."""
+    d, c = dst_t.shape
+    n = src_rows.shape[0]
     return pl.pallas_call(
-        _exchange_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(n,),
-            in_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # capacity
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # cache
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # cap_acc
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # cache_acc
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # freq
-            ],
-            out_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-            ],
-            scratch_shapes=[
-                MemorySpace.VMEM((1, d), capacity.dtype),
-                MemorySpace.VMEM((1, 1), jnp.float32),
-                MemorySpace.VMEM((1, 1), jnp.float32),
-                SemaphoreType.DMA((3,)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((r, d), capacity.dtype),
-            jax.ShapeDtypeStruct((c, d), cache.dtype),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((c, 1), jnp.float32),
-            jax.ShapeDtypeStruct((c, 1), jnp.float32),
+        _move_kernel,
+        grid=(n // ROW_BLOCK,),
+        in_specs=[
+            pl.BlockSpec((ROW_BLOCK,), lambda i: (i,),
+                         memory_space=MemorySpace.SMEM),
+            pl.BlockSpec((ROW_BLOCK,), lambda i: (i,),
+                         memory_space=MemorySpace.SMEM),
+            pl.BlockSpec(memory_space=MemorySpace.ANY),
+            pl.BlockSpec(memory_space=MemorySpace.ANY),
         ],
-        input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3, 8: 4},
+        out_specs=pl.BlockSpec(memory_space=MemorySpace.ANY),
+        scratch_shapes=[
+            MemorySpace.VMEM((d, LANE), src_t.dtype),
+            MemorySpace.VMEM((d, LANE), dst_t.dtype),
+            MemorySpace.SMEM((2,), jnp.int32),
+            SemaphoreType.DMA((2,)),
+        ],
+        out_shape=jax.ShapeDtypeStruct((d, c), dst_t.dtype),
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(slots, evict_rows, fetch_rows, counts, capacity, cache,
-      cap_accum.reshape(r, 1).astype(jnp.float32),
-      cache_accum.reshape(c, 1).astype(jnp.float32),
-      freq.reshape(c, 1).astype(jnp.float32))
+        name="move_rows",
+    )(src_rows, dst_rows, src_t, dst_t)
 
 
-# ---------------------------------------------------------------------------
-# split async exchange: fetch (capacity -> shadow) / commit (shadow -> cache)
-# ---------------------------------------------------------------------------
-#
-# The synchronous cache_exchange above blocks the step on its worklist: the
-# fetch DMA sits on the critical path between batch k's update and batch
-# k+1's forward. The async stream (core/cache.py AsyncCacheState) splits it:
-#
-#   fetch   capacity rows -> a fresh SHADOW slab. No cache/capacity output,
-#           no donation — it only READS the tiers, so it runs concurrently
-#           with the in-flight batch's dense compute.
-#   commit  at the step boundary: dirty-victim writeback (cache -> capacity,
-#           reading the POST-update cache) + shadow row -> cache slot. Only
-#           device-resident row copies — the slow capacity fetch already
-#           happened off the critical path.
-#
-# fetch + commit over one worklist == one cache_exchange (asserted in
-# tests/test_cache_async.py against kernels/ref.py oracles).
+def move_rows(src: jax.Array, dst: jax.Array, src_rows: jax.Array,
+              dst_rows: jax.Array, interpret: bool = False) -> jax.Array:
+    """dst with dst[dst_rows[i]] = src[src_rows[i]] for every entry where
+    both are >= 0, through `move_rows_kernel` on the (d, rows) views.
+    src: (R, d); dst: (C, d); src_rows/dst_rows: (N,) int32."""
+    c = dst.shape[0]
+    src_rows = _pad_axis(src_rows.astype(jnp.int32), ROW_BLOCK, 0, -1)
+    dst_rows = _pad_axis(dst_rows.astype(jnp.int32), ROW_BLOCK, 0, -1)
+    out = move_rows_kernel(_pad_axis(src.T, LANE, 1),
+                           _pad_axis(dst.T, LANE, 1), src_rows, dst_rows,
+                           interpret=interpret)
+    return out[:, :c].T
 
 
-def _fetch_kernel(fetch_ref, capacity_ref, cap_acc_ref, shadow_out,
-                  shadow_acc_out, row_vmem, acc_vmem, sems):
-    """Grid step i gathers capacity row fetch_ref[i] into shadow row i.
-
-    fetch: (N,) SMEM scalar-prefetch (-1 = pad, zero-fills the shadow row);
-    capacity: (R, D), cap_acc: (R, 1) HBM read-only; shadow_out: (N, D),
-    shadow_acc_out: (N, 1) HBM; row_vmem: (1, D); acc_vmem: (1, 1)."""
-    i = pl.program_id(0)
-    ft = fetch_ref[i]
-
-    @pl.when(ft >= 0)
-    def _gather():
-        cp_r = pltpu.make_async_copy(capacity_ref.at[pl.ds(ft, 1)], row_vmem,
-                                     sems.at[0])
-        cp_a = pltpu.make_async_copy(cap_acc_ref.at[pl.ds(ft, 1)], acc_vmem,
-                                     sems.at[1])
-        cp_r.start()
-        cp_a.start()
-        cp_r.wait()
-        cp_a.wait()
-
-    @pl.when(ft < 0)
-    def _zero():
-        row_vmem[...] = jnp.zeros(row_vmem.shape, row_vmem.dtype)
-        acc_vmem[...] = jnp.zeros(acc_vmem.shape, acc_vmem.dtype)
-
-    cp_wr = pltpu.make_async_copy(row_vmem, shadow_out.at[pl.ds(i, 1)],
-                                  sems.at[0])
-    cp_wa = pltpu.make_async_copy(acc_vmem, shadow_acc_out.at[pl.ds(i, 1)],
-                                  sems.at[1])
-    cp_wr.start()
-    cp_wa.start()
-    cp_wr.wait()
-    cp_wa.wait()
+def _valid_or_skip(mask: jax.Array, rows: jax.Array) -> jax.Array:
+    return jnp.where(mask, rows, -1)
 
 
-# NO donation: fetch only reads the tiers — the caller's capacity array and
-# the in-flight batch's cache stay live while the DMA is in flight.
+def _bucket(*rows: jax.Array) -> list[jax.Array]:
+    """Pad worklist arrays with -1 (skip) to a power-of-two length of at
+    least ROW_BLOCK, so the per-batch miss counts of a serving or training
+    run compile a handful of kernel variants, not one per count."""
+    n = rows[0].shape[0]
+    target = max(ROW_BLOCK, 1 << max(n - 1, 0).bit_length())
+    return [jnp.pad(r, (0, target - n), constant_values=-1) for r in rows]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",),
+                   donate_argnums=(0, 1, 2, 3, 4))
+def _exchange_kernel_jit(capacity, cache, cap_accum, cache_accum, freq,
+                         slots, evict_rows, fetch_rows, counts, interpret):
+    """Writeback pass then fetch pass (entry order is immaterial: slots are
+    distinct and evicted rows never coincide with fetched ones), the
+    accumulators and LFU seeds moved exactly as `ref.cache_exchange_ref`
+    moves them."""
+    r, c = capacity.shape[0], cache.shape[0]
+    live = slots >= 0
+    wb = live & (evict_rows >= 0)
+    ft = live & (fetch_rows >= 0)
+    safe_slot = jnp.where(live, slots, 0)
+    capacity = move_rows(cache, capacity, _valid_or_skip(wb, slots),
+                         evict_rows, interpret)
+    cache = move_rows(capacity, cache, fetch_rows,
+                      _valid_or_skip(ft, slots), interpret)
+    cap_accum = cap_accum.at[jnp.where(wb, evict_rows, r)].set(
+        cache_accum[safe_slot], mode="drop")
+    dst = jnp.where(ft, slots, c)                             # c drops
+    cache_accum = cache_accum.at[dst].set(
+        cap_accum[jnp.where(ft, fetch_rows, 0)], mode="drop")
+    freq = freq.at[dst].set(counts.astype(freq.dtype), mode="drop")
+    return capacity, cache, cap_accum, cache_accum, freq
+
+
+def gather_rows(table: jax.Array, rows: jax.Array,
+                use_kernel: bool | None = None,
+                interpret: bool = False) -> jax.Array:
+    """(N, d) rows `table[rows]`, zeros where rows < 0. On TPU (or under
+    `interpret`) the row-move kernel reads the table in place; an XLA
+    gather would first relayout the whole narrow table into a padded
+    row-major copy (2x its bytes at d=64)."""
+    rows = rows.astype(jnp.int32)
+    if use_pallas(use_kernel) or interpret:
+        n = rows.shape[0]
+        return move_rows(table, jnp.zeros((n, table.shape[1]), table.dtype),
+                         rows, jnp.arange(n, dtype=jnp.int32), interpret)
+    valid = rows >= 0
+    return jnp.where(valid[:, None],
+                     jnp.take(table, jnp.where(valid, rows, 0), axis=0),
+                     0).astype(table.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def cache_fetch_kernel(capacity: jax.Array, cap_accum: jax.Array,
-                       fetch_rows: jax.Array, interpret: bool = False):
-    """capacity: (R, D) with D % 128 == 0; cap_accum: (R,) fp32;
-    fetch_rows: (N,) int32 (-1 = pad). Returns (shadow (N, D),
-    shadow_accum (N, 1)) — a fresh slab, the tiers are untouched."""
-    r, d = capacity.shape
-    n = fetch_rows.shape[0]
-    return pl.pallas_call(
-        _fetch_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n,),
-            in_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # capacity
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # cap_acc
-            ],
-            out_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-            ],
-            scratch_shapes=[
-                MemorySpace.VMEM((1, d), capacity.dtype),
-                MemorySpace.VMEM((1, 1), jnp.float32),
-                SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((n, d), capacity.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(fetch_rows, capacity, cap_accum.reshape(r, 1).astype(jnp.float32))
+def _fetch_kernel_jit(capacity, cap_accum, fetch_rows, interpret):
+    """Gather into a fresh zero slab: -1 entries leave zero rows."""
+    shadow = gather_rows(capacity, fetch_rows, True, interpret)
+    valid = fetch_rows >= 0
+    return shadow, jnp.where(valid, cap_accum[jnp.where(valid, fetch_rows,
+                                                        0)], 0.0)
 
 
-def _fetch_chunked_kernel(starts_ref, capacity_ref, cap_acc_ref, shadow_out,
-                          shadow_acc_out, blk_vmem, acc_vmem, sems, *,
-                          chunk: int):
-    """Grid step k gathers the `chunk`-row capacity block at starts_ref[k]
-    into shadow rows [k*chunk, (k+1)*chunk) — ONE DMA descriptor per block
-    instead of one per row.
-
-    starts: (K,) SMEM scalar-prefetch (-1 = pad, zero-fills the block);
-    capacity: (R, D), cap_acc: (R, 1) HBM read-only; shadow_out:
-    (K*chunk, D), shadow_acc_out: (K*chunk, 1) HBM; blk_vmem: (chunk, D);
-    acc_vmem: (chunk, 1)."""
-    k = pl.program_id(0)
-    s = starts_ref[k]
-
-    @pl.when(s >= 0)
-    def _gather():
-        cp_r = pltpu.make_async_copy(capacity_ref.at[pl.ds(s, chunk)],
-                                     blk_vmem, sems.at[0])
-        cp_a = pltpu.make_async_copy(cap_acc_ref.at[pl.ds(s, chunk)],
-                                     acc_vmem, sems.at[1])
-        cp_r.start()
-        cp_a.start()
-        cp_r.wait()
-        cp_a.wait()
-
-    @pl.when(s < 0)
-    def _zero():
-        blk_vmem[...] = jnp.zeros(blk_vmem.shape, blk_vmem.dtype)
-        acc_vmem[...] = jnp.zeros(acc_vmem.shape, acc_vmem.dtype)
-
-    cp_wr = pltpu.make_async_copy(
-        blk_vmem, shadow_out.at[pl.ds(k * chunk, chunk)], sems.at[0])
-    cp_wa = pltpu.make_async_copy(
-        acc_vmem, shadow_acc_out.at[pl.ds(k * chunk, chunk)], sems.at[1])
-    cp_wr.start()
-    cp_wa.start()
-    cp_wr.wait()
-    cp_wa.wait()
-
-
-# NO donation, same reason as cache_fetch_kernel: read-only on the tiers.
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def cache_fetch_chunked_kernel(capacity: jax.Array, cap_accum: jax.Array,
-                               chunk_starts: jax.Array, chunk: int,
-                               interpret: bool = False):
-    """capacity: (R, D) with D % 128 == 0; cap_accum: (R,) fp32;
-    chunk_starts: (K,) int32 block starts, clamped so start+chunk <= R
-    (-1 = pad). Returns (shadow (K*chunk, D), shadow_accum (K*chunk, 1))
-    — a fresh slab, the tiers are untouched."""
-    r, d = capacity.shape
-    k = chunk_starts.shape[0]
-    return pl.pallas_call(
-        functools.partial(_fetch_chunked_kernel, chunk=chunk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(k,),
-            in_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # capacity
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # cap_acc
-            ],
-            out_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-            ],
-            scratch_shapes=[
-                MemorySpace.VMEM((chunk, d), capacity.dtype),
-                MemorySpace.VMEM((chunk, 1), jnp.float32),
-                SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((k * chunk, d), capacity.dtype),
-            jax.ShapeDtypeStruct((k * chunk, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(chunk_starts, capacity, cap_accum.reshape(r, 1).astype(jnp.float32))
-
-
-def _commit_kernel(slots_ref, evict_ref, fetch_ref, src_pos_ref, shadow_ref,
-                   shadow_acc_ref, capacity_ref, cache_ref, cap_acc_ref,
-                   cache_acc_ref, capacity_out, cache_out, cap_acc_out,
-                   cache_acc_out, row_vmem, acc_vmem, sems):
-    """Grid step i installs shadow row src_pos_ref[i] into cache slot
-    slots_ref[i], writing the slot's dirty victim back to capacity row
-    evict_ref[i] first.
-
-    slots/evict/fetch/src_pos: (N,) SMEM scalar-prefetch (-1 = skip; fetch
-    gates the install — pure-writeback entries keep the slot; src_pos is
-    arange(N) for a one-row-per-entry shadow or the coalescer's `pos` for a
-    chunk-granular slab); shadow: (M, D), shadow_acc: (M, 1) HBM read-only
-    with M >= N; capacity/(R, D), cache/(C, D), cap_acc/(R, 1),
-    cache_acc/(C, 1) HBM io-aliased in->out."""
-    i = pl.program_id(0)
-    s = slots_ref[i]
-    ev = evict_ref[i]
-    ft = fetch_ref[i]
-    sp = src_pos_ref[i]
-
-    @pl.when((s >= 0) & (ev >= 0))
-    def _writeback():
-        cp_r = pltpu.make_async_copy(cache_ref.at[pl.ds(s, 1)], row_vmem,
-                                     sems.at[0])
-        cp_a = pltpu.make_async_copy(cache_acc_ref.at[pl.ds(s, 1)], acc_vmem,
-                                     sems.at[1])
-        cp_r.start()
-        cp_a.start()
-        cp_r.wait()
-        cp_a.wait()
-        cp_wr = pltpu.make_async_copy(row_vmem, capacity_out.at[pl.ds(ev, 1)],
-                                      sems.at[0])
-        cp_wa = pltpu.make_async_copy(acc_vmem, cap_acc_out.at[pl.ds(ev, 1)],
-                                      sems.at[1])
-        cp_wr.start()
-        cp_wa.start()
-        cp_wr.wait()
-        cp_wa.wait()
-
-    @pl.when((s >= 0) & (ft >= 0))
-    def _install():
-        cp_r = pltpu.make_async_copy(shadow_ref.at[pl.ds(sp, 1)], row_vmem,
-                                     sems.at[0])
-        cp_a = pltpu.make_async_copy(shadow_acc_ref.at[pl.ds(sp, 1)],
-                                     acc_vmem, sems.at[1])
-        cp_r.start()
-        cp_a.start()
-        cp_r.wait()
-        cp_a.wait()
-        cp_wr = pltpu.make_async_copy(row_vmem, cache_out.at[pl.ds(s, 1)],
-                                      sems.at[0])
-        cp_wa = pltpu.make_async_copy(acc_vmem, cache_acc_out.at[pl.ds(s, 1)],
-                                      sems.at[1])
-        cp_wr.start()
-        cp_wa.start()
-        cp_wr.wait()
-        cp_wa.wait()
-
-
-# the four tier arrays are donated/io-aliased (in-place row swap); the
-# shadow slab is consumed by this call but NOT aliased (different height)
 @functools.partial(jax.jit, static_argnames=("interpret",),
-                   donate_argnums=(0, 1))
-def cache_commit_kernel(capacity: jax.Array, cache: jax.Array,
-                        cap_accum: jax.Array, cache_accum: jax.Array,
-                        shadow: jax.Array, shadow_accum: jax.Array,
-                        slots: jax.Array, evict_rows: jax.Array,
-                        fetch_rows: jax.Array, src_pos: jax.Array,
-                        interpret: bool = False):
-    """capacity: (R, D), cache: (C, D), shadow: (M, D) with D % 128 == 0 and
-    M >= N; cap_accum: (R, 1), cache_accum: (C, 1), shadow_accum: (M, 1)
-    fp32; slots/evict_rows/fetch_rows/src_pos: (N,) int32 (-1 = skip; fetch
-    gates the shadow install, which reads shadow row src_pos[i]). Returns
-    (capacity', cache', cap_accum', cache_accum') updated in place
-    (io aliasing)."""
-    r, d = capacity.shape
-    c = cache.shape[0]
-    n = slots.shape[0]
-    m = shadow.shape[0]
-    return pl.pallas_call(
-        _commit_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(n,),
-            in_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # shadow
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # shadow_acc
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # capacity
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # cache
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # cap_acc
-                pl.BlockSpec(memory_space=MemorySpace.ANY),  # cache_acc
-            ],
-            out_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-            ],
-            scratch_shapes=[
-                MemorySpace.VMEM((1, d), capacity.dtype),
-                MemorySpace.VMEM((1, 1), jnp.float32),
-                SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((r, d), capacity.dtype),
-            jax.ShapeDtypeStruct((c, d), cache.dtype),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((c, 1), jnp.float32),
-        ],
-        input_output_aliases={6: 0, 7: 1, 8: 2, 9: 3},
-        interpret=interpret,
-    )(slots, evict_rows, fetch_rows, src_pos, shadow,
-      shadow_accum.reshape(m, 1), capacity, cache,
-      cap_accum.reshape(r, 1).astype(jnp.float32),
-      cache_accum.reshape(c, 1).astype(jnp.float32))
+                   donate_argnums=(0, 1, 2, 3))
+def _commit_kernel_jit(capacity, cache, cap_accum, cache_accum, shadow,
+                       shadow_accum, slots, evict_rows, fetch_rows, src_pos,
+                       interpret):
+    """Writeback pass then install pass, side arrays as
+    `ref.cache_commit_ref` moves them."""
+    r, c = capacity.shape[0], cache.shape[0]
+    live = slots >= 0
+    wb = live & (evict_rows >= 0)
+    ft = live & (fetch_rows >= 0)
+    safe_slot = jnp.where(live, slots, 0)
+    capacity = move_rows(cache, capacity, _valid_or_skip(wb, slots),
+                         evict_rows, interpret)
+    cache = move_rows(shadow.astype(cache.dtype), cache,
+                      _valid_or_skip(ft, src_pos),
+                      _valid_or_skip(ft, slots), interpret)
+    cap_accum = cap_accum.at[jnp.where(wb, evict_rows, r)].set(
+        cache_accum[safe_slot], mode="drop")
+    cache_accum = cache_accum.at[jnp.where(ft, slots, c)].set(
+        shadow_accum[src_pos], mode="drop")
+    return capacity, cache, cap_accum, cache_accum
 
 
 # ---------------------------------------------------------------------------
@@ -474,8 +255,8 @@ def cache_exchange(capacity: jax.Array, cache: jax.Array,
                    ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                               jax.Array]:
     """Batched eviction-writeback + fetch-on-miss between the capacity tier
-    and the device cache. See cache_exchange_kernel / ref.cache_exchange_ref
-    for the worklist contract. Returns (capacity', cache', cap_accum',
+    and the device cache. See ref.cache_exchange_ref for the worklist
+    contract. Returns (capacity', cache', cap_accum',
     cache_accum', freq').
 
     ALL FIVE ARRAYS ARE DONATED: the swap must update a few rows in place,
@@ -485,13 +266,13 @@ def cache_exchange(capacity: jax.Array, cache: jax.Array,
     evict_rows = evict_rows.astype(jnp.int32)
     fetch_rows = fetch_rows.astype(jnp.int32)
     counts = counts.astype(jnp.float32)
-    if _use_pallas(use_kernel) or interpret:
-        d = capacity.shape[1]
-        new_cap, new_cache, new_ca, new_cc, new_f = cache_exchange_kernel(
-            _pad_lane(capacity), _pad_lane(cache), cap_accum, cache_accum,
-            freq, slots, evict_rows, fetch_rows, counts, interpret=interpret)
-        return (new_cap[:, :d], new_cache[:, :d], new_ca[:, 0], new_cc[:, 0],
-                new_f[:, 0])
+    if use_pallas(use_kernel) or interpret:
+        slots, evict_rows, fetch_rows = _bucket(slots, evict_rows,
+                                                fetch_rows)
+        counts = jnp.pad(counts, (0, slots.shape[0] - counts.shape[0]))
+        return _exchange_kernel_jit(capacity, cache, cap_accum, cache_accum,
+                                    freq, slots, evict_rows, fetch_rows,
+                                    counts, interpret)
     return _exchange_ref_jit(capacity, cache, cap_accum, cache_accum,
                              freq, slots, evict_rows, fetch_rows, counts)
 
@@ -507,19 +288,13 @@ def cache_fetch(capacity: jax.Array, cap_accum: jax.Array,
     """FETCH half of the split async exchange: gather `fetch_rows` (+ their
     accumulators) from the capacity tier into a fresh shadow slab. Read-only
     on the tiers (nothing donated) so it overlaps the in-flight batch's
-    compute. Returns (shadow (N, D), shadow_accum (N,)).
-
-    The Pallas path requires D % 128 == 0; an unaligned D would force a
-    full O(R x D') pad-copy of the capacity tier EVERY call (the fetch
-    cannot donate, unlike the exchange), so unless `interpret` explicitly
-    asks for the kernel, unaligned tables take the jnp gather — a cheap
-    XLA dynamic-gather that keeps the fetch off the critical path."""
+    compute. Returns (shadow (N, D), shadow_accum (N,))."""
     fetch_rows = fetch_rows.astype(jnp.int32)
-    d = capacity.shape[1]
-    if (_use_pallas(use_kernel) and d % LANE == 0) or interpret:
-        shadow, shadow_acc = cache_fetch_kernel(
-            _pad_lane(capacity), cap_accum, fetch_rows, interpret=interpret)
-        return shadow[:, :d], shadow_acc[:, 0]
+    if use_pallas(use_kernel) or interpret:
+        n = fetch_rows.shape[0]
+        shadow, shadow_acc = _fetch_kernel_jit(
+            capacity, cap_accum, _bucket(fetch_rows)[0], interpret)
+        return shadow[:n], shadow_acc[:n]
     return _fetch_ref_jit(capacity, cap_accum, fetch_rows)
 
 
@@ -535,19 +310,20 @@ def cache_fetch_chunked(capacity: jax.Array, cap_accum: jax.Array,
                         interpret: bool = False
                         ) -> tuple[jax.Array, jax.Array]:
     """CHUNK-granular fetch: gather K contiguous `chunk`-row capacity blocks
-    (+ accumulators) into one (K*chunk, D) shadow slab — one DMA descriptor
-    per BLOCK. `chunk_starts` comes from kernels/sparse_plan.coalesce_rows
+    (+ accumulators) into one (K*chunk, D) shadow slab — one tile DMA per
+    block rather than one per row. `chunk_starts` comes from kernels/sparse_plan.coalesce_rows
     (starts clamped so start+chunk <= R; -1 = pad, zero block). Read-only on
     the tiers, same overlap contract as `cache_fetch`. Pair with
     `cache_commit(..., src_pos=pos)` to install individual rows out of the
     block slab. Returns (shadow (K*chunk, D), shadow_accum (K*chunk,))."""
     chunk_starts = chunk_starts.astype(jnp.int32)
-    d = capacity.shape[1]
-    if (_use_pallas(use_kernel) and d % LANE == 0) or interpret:
-        shadow, shadow_acc = cache_fetch_chunked_kernel(
-            _pad_lane(capacity), cap_accum, chunk_starts, chunk,
-            interpret=interpret)
-        return shadow[:, :d], shadow_acc[:, 0]
+    if use_pallas(use_kernel) or interpret:
+        # the block's rows share (d, 128) tiles, so the row-move kernel
+        # reads each tile once: one DMA per block, as the ref slab layout
+        rows = chunk_starts[:, None] + jnp.arange(chunk, dtype=jnp.int32)
+        rows = jnp.where(chunk_starts[:, None] >= 0, rows, -1).reshape(-1)
+        return cache_fetch(capacity, cap_accum, rows, interpret=interpret,
+                           use_kernel=use_kernel)
     return _fetch_chunked_ref_jit(capacity, cap_accum, chunk_starts, chunk)
 
 
@@ -585,13 +361,12 @@ def cache_commit(capacity: jax.Array, cache: jax.Array, cap_accum: jax.Array,
         src_pos = jnp.arange(n, dtype=jnp.int32)
     else:
         src_pos = src_pos.astype(jnp.int32)
-    if _use_pallas(use_kernel) or interpret:
-        d = capacity.shape[1]
-        new_cap, new_cache, new_ca, new_cc = cache_commit_kernel(
-            _pad_lane(capacity), _pad_lane(cache), cap_accum, cache_accum,
-            _pad_lane(shadow), shadow_accum, slots, evict_rows, fetch_rows,
-            src_pos, interpret=interpret)
-        return new_cap[:, :d], new_cache[:, :d], new_ca[:, 0], new_cc[:, 0]
+    if use_pallas(use_kernel) or interpret:
+        slots, evict_rows, fetch_rows, src_pos = _bucket(
+            slots, evict_rows, fetch_rows, src_pos)
+        return _commit_kernel_jit(capacity, cache, cap_accum, cache_accum,
+                                  shadow, shadow_accum, slots, evict_rows,
+                                  fetch_rows, src_pos, interpret)
     return _commit_ref_jit(capacity, cache, cap_accum, cache_accum,
                            shadow, shadow_accum, slots, evict_rows,
                            fetch_rows, src_pos)

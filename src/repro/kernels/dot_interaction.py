@@ -3,7 +3,7 @@
 DLRM's interaction (paper section III-A.3) forms Z Z^T per example over the
 stacked feature matrix Z = [dense_proj; pooled_emb_1; ...] (F, D) and keeps
 the strictly-lower triangle. This kernel keeps Z in VMEM per batch tile,
-runs the (F, D) x (D, F) contraction on the MXU with fp32 accumulation, and
+runs the (F, D) x (D, F) contraction on the MXU at full fp32 precision, and
 masks the upper triangle with an iota comparison in VREGs (no gather — TPU
 vector units have no efficient in-kernel gather). The cheap triangle packing
 (a static-index gather over the already-masked (F, F) tile) remains in XLA
@@ -26,6 +26,7 @@ def _dot_int_kernel(z_ref, out_ref):
     f = z.shape[1]
     s = jax.lax.dot_general(
         z, z, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                  # (tb, F, F)
     rows = jax.lax.broadcasted_iota(jnp.int32, (f, f), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (f, f), 1)
@@ -47,4 +48,5 @@ def dot_interaction_kernel(z: jax.Array, tile_b: int = 8,
         out_specs=pl.BlockSpec((tile_b, f, f), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, f, f), z.dtype),
         interpret=interpret,
+        name="dot_interaction",
     )(z)

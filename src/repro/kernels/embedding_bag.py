@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import MemorySpace, SemaphoreType
+from jax.experimental.pallas.tpu import MemorySpace, SemaphoreType
 
 # the dedup kernel keeps the whole pooled output resident in VMEM across
 # the grid; beyond this it must fall back to the legacy kernel (bag-tiled

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import MemorySpace, SemaphoreType
+from jax.experimental.pallas.tpu import MemorySpace, SemaphoreType
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, kbuf, vbuf, sems, *,

@@ -22,17 +22,16 @@ from repro.kernels.dot_interaction import dot_interaction_kernel
 from repro.kernels.embedding_bag import (dedup_embedding_bag_kernel,
                                          embedding_bag_kernel)
 from repro.kernels.flash_attention import flash_attention_kernel
-from repro.kernels.rowwise_adagrad import rowwise_adagrad_kernel
 from repro.kernels.sparse_plan import SparsePlan, build_sparse_plan
-from repro.kernels.sparse_update import (
-    fused_bag_backward_adagrad_kernel,
-    fused_bag_backward_adagrad_segments_kernel)
+from repro.kernels.sparse_update import rowwise_adagrad_apply
 
 LANE = 128
 SUBLANE = 8
 
 
-def _use_pallas(force: bool | None) -> bool:
+def use_pallas(force: bool | None) -> bool:
+    """Kernel dispatch shared by every wrapper: `force` when given, else
+    the Pallas kernels exactly when the default backend is a TPU."""
     if force is not None:
         return force
     return jax.default_backend() == "tpu"
@@ -58,7 +57,7 @@ def embedding_bag(table: jax.Array, indices: jax.Array, mode: str = "sum",
                   interpret: bool = False) -> jax.Array:
     """Pooled multi-hot lookup. table: (H, D); indices: (B, L) int32, -1 pads.
     Returns (B, D)."""
-    if _use_pallas(use_kernel) or interpret:
+    if use_pallas(use_kernel) or interpret:
         d = table.shape[1]
         tp = _pad_to(table, LANE, 1)
         out = embedding_bag_kernel(tp, indices, mode=mode,
@@ -121,7 +120,7 @@ def dedup_embedding_bag(table: jax.Array, indices: jax.Array,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _dedup_bag(table, indices, rows, offs, bags, mode, use_kernel,
                interpret):
-    if _use_pallas(use_kernel) or interpret:
+    if use_pallas(use_kernel) or interpret:
         d = table.shape[1]
         tp = _pad_to(table, LANE, 1)
         out = dedup_embedding_bag_kernel(tp, rows, offs, bags,
@@ -160,7 +159,7 @@ def dot_interaction(z: jax.Array, tile_b: int = 8,
                     use_kernel: bool | None = None,
                     interpret: bool = False) -> jax.Array:
     """z: (B, F, D) -> (B, F*(F-1)//2) strict-lower-triangle pairwise dots."""
-    if _use_pallas(use_kernel) or interpret:
+    if use_pallas(use_kernel) or interpret:
         b, f, d = z.shape
         zp = _pad_to(_pad_to(z, LANE, 2), SUBLANE, 1)
         tb = tile_b if b % tile_b == 0 else 1
@@ -191,25 +190,6 @@ dot_interaction.defvjp(_dot_fwd, _dot_bwd)
 # ---------------------------------------------------------------------------
 
 
-def _pad_scale_lr(table, grads, lr):
-    """Lane-pad (table, grads) and compensate lr for the padded mean(g^2).
-
-    The kernels compute mean(g^2) over the PADDED dim Dp; scaling the padded
-    grads by sqrt(Dp/d) makes that equal the true mean over d, and lr is
-    divided by the same factor so the weight delta lr_k * g_k * rsqrt(...)
-    stays lr * g * rsqrt(...). When D is already lane-aligned (every
-    production config: d=128) all three pass through UNTOUCHED — no
-    whole-table pad copy and no full-payload scale multiply per step.
-    """
-    d = table.shape[1]
-    tp = _pad_to(table, LANE, 1)
-    if tp.shape[1] == d:
-        return tp, grads, jnp.asarray(lr, jnp.float32)
-    scale = np.sqrt(tp.shape[1] / d).astype(np.float32)
-    return tp, _pad_to(grads, LANE, 1) * scale, \
-        jnp.asarray(lr, jnp.float32) / scale
-
-
 def rowwise_adagrad_update(table: jax.Array, accum: jax.Array,
                            indices: jax.Array, grads: jax.Array,
                            lr, eps: float = 1e-8,
@@ -224,13 +204,10 @@ def rowwise_adagrad_update(table: jax.Array, accum: jax.Array,
     Prefer `fused_sparse_backward` where the caller holds (idx, pooled
     grads): it skips the per-lookup broadcast this signature forces.
     """
-    h, d = table.shape
-    if _use_pallas(use_kernel) or interpret:
-        uniq, gsum = ref.dedup_grads_ref(indices, grads, h)
-        tp, gp, lr_eff = _pad_scale_lr(table, gsum, lr)
-        new_t, new_a = rowwise_adagrad_kernel(tp, accum, uniq, gp, lr_eff,
-                                              eps=eps, interpret=interpret)
-        return new_t[:, :d], new_a[:, 0]
+    if use_pallas(use_kernel) or interpret:
+        uniq, gsum = ref.dedup_grads_ref(indices, grads, table.shape[0])
+        return rowwise_adagrad_apply(table, accum, uniq, gsum, lr, eps,
+                                     interpret)
     return ref.rowwise_adagrad_ref(table, accum, indices, grads, lr, eps)
 
 
@@ -250,21 +227,22 @@ def fused_sparse_backward(table: jax.Array, accum: jax.Array,
     with one built ahead of time (`data.sparse_plan_hook` builds batch k+1's
     in the reader thread while batch k computes). Returns (table', accum').
 
-    Matches `rowwise_adagrad_update` fed the legacy broadcast layout
-    bit-for-bit (same per-row accumulation order — the planner's stable
-    sort), minus the (B*F*L, D) intermediates.
+    The jnp path is bit-identical to `rowwise_adagrad_update` fed the
+    legacy broadcast layout (same per-row accumulation order — the
+    planner's stable sort), minus the (B*F*L, D) intermediates. The kernel
+    path aggregates with the same `ref.bag_grad_sums` and applies the
+    update in place (kernels/sparse_update.py).
     """
-    h, d = table.shape
+    d = table.shape[1]
     if plan is None:
         assert idx is not None, "need idx to build a SparsePlan"
         plan = build_sparse_plan(idx)
     pooled2 = pooled_grad.reshape(-1, d)
-    if _use_pallas(use_kernel) or interpret:
-        tp, gp, lr_eff = _pad_scale_lr(table, pooled2, lr)
-        new_t, new_a = fused_bag_backward_adagrad_kernel(
-            tp, accum, plan.unique_rows, plan.bag_offsets, plan.bag_ids,
-            gp, lr_eff, eps=eps, interpret=interpret)
-        return new_t[:, :d], new_a[:, 0]
+    if use_pallas(use_kernel) or interpret:
+        gsum = ref.bag_grad_sums(plan.unique_rows, plan.bag_offsets,
+                                 plan.bag_ids, pooled2)
+        return rowwise_adagrad_apply(table, accum, plan.unique_rows, gsum,
+                                     lr, eps, interpret)
     return ref.fused_bag_backward_adagrad_ref(
         table, accum, plan.unique_rows, plan.bag_offsets, plan.bag_ids,
         pooled2, lr, eps)
@@ -292,28 +270,25 @@ def fused_sparse_backward_segments(table: jax.Array, accum: jax.Array,
     covered row updates with bits identical to the unsegmented
     `fused_sparse_backward` (asserted in tests/test_cache_multihost.py).
     """
-    h, d = table.shape
+    d = table.shape[1]
     s = seg_rows.shape[0]
     if seg_base is None:
         seg_base = jnp.zeros((s,), jnp.int32)
     pooled2 = pooled_grad.reshape(-1, d)
-    if _use_pallas(use_kernel) or interpret:
-        tp, gp, lr_eff = _pad_scale_lr(table, pooled2, lr)
-        new_t, new_a = fused_bag_backward_adagrad_segments_kernel(
-            tp, accum, seg_rows, seg_offsets, bag_ids, gp, lr_eff,
-            jnp.asarray(seg_base, jnp.int32), eps=eps, interpret=interpret)
-        return new_t[:, :d], new_a[:, 0]
-    # jnp path: segments are disjoint row ranges of one plan, so the
-    # flattened (rows rebased, offsets kept absolute) view is itself a
-    # valid abs-offset plan over the whole table
+    # segments are disjoint row ranges of one plan, so the flattened (rows
+    # rebased, offsets kept absolute) view is itself a valid abs-offset
+    # plan over the whole table
     rows_flat = jnp.where(seg_rows >= 0,
                           seg_rows + jnp.asarray(seg_base, jnp.int32)[:, None],
                           -1).reshape(-1)
     offs_flat = jnp.concatenate(
         [seg_offsets[:, :-1].reshape(-1), seg_offsets[-1:, -1]])
+    if use_pallas(use_kernel) or interpret:
+        gsum = ref.bag_grad_sums_abs(offs_flat, bag_ids, pooled2)
+        return rowwise_adagrad_apply(table, accum, rows_flat, gsum, lr, eps,
+                                     interpret)
     return ref.fused_bag_backward_adagrad_abs_ref(
         table, accum, rows_flat, offs_flat, bag_ids, pooled2, lr, eps)
-
 
 # ---------------------------------------------------------------------------
 # flash_attention (forward; training uses the XLA blockwise fallback)
@@ -327,7 +302,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: bool = False) -> jax.Array:
     """q, k, v: (b, s, h, dh) (layer-zoo layout). Pads dh to the lane width
     and s to the block size; padded KV rows are masked by causality."""
-    if not (_use_pallas(use_kernel) or interpret):
+    if not (use_pallas(use_kernel) or interpret):
         from repro.kernels.ref import flash_attention_ref
         out = flash_attention_ref(q.swapaxes(1, 2), k.swapaxes(1, 2),
                                   v.swapaxes(1, 2), causal)

@@ -66,7 +66,8 @@ def dot_interaction_ref(z: jax.Array) -> jax.Array:
     """
     f = z.shape[1]
     s = jnp.einsum("bfd,bgd->bfg", z.astype(jnp.float32),
-                   z.astype(jnp.float32))
+                   z.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     rows, cols = np.tril_indices(f, -1)
     return s[:, rows, cols].astype(z.dtype)
 

@@ -1,28 +1,31 @@
-"""Pallas TPU kernel: fused sparse backward — bag-gradient gather +
-aggregation + row-wise AdaGrad in ONE pass over unique rows.
+"""Pallas TPU kernel: row-wise AdaGrad applied in place to a table's
+touched rows — the training hot spot the paper calls out ("not optimized
+for gradient aggregation", section VII).
 
-This supersedes the two-pass `dedup_grads_ref` + `rowwise_adagrad_kernel`
-pipeline for the training hot spot the paper calls out ("not optimized for
-gradient aggregation", section VII). The host/device planner
-(kernels/sparse_plan.py) has already bucketed the batch's lookup stream by
-unique row — int32 arrays only — so per grid step (one unique row) this
-kernel:
+The caller has already aggregated the batch's gradients per UNIQUE row
+(`ref.bag_grad_sums` over a `SparsePlan`, or `ref.dedup_grads_ref` over
+per-lookup grads) into `gsum`, and gathered each row's accumulator. This
+kernel then walks the unique rows in ascending order and, per row,
 
-    DMA row + accumulator in (HBM->VMEM)
-    for each referencing bag (CSR slice of the plan):
-        DMA the bag's POOLED (1, D) gradient in — DOUBLE-BUFFERED, bag
-        j+1's fetch rides behind bag j's accumulate — then add in VMEM
     acc' = acc + mean(g^2);  w' = w - lr * g * rsqrt(acc' + eps)
-    DMA row + accumulator back, in place via io aliasing
 
-No `(B*F*L, D)` per-lookup gradient tensor ever exists: the only full-width
-traffic is the pooled `(B*F, D)` grads (which autodiff produces anyway) and
-the touched table rows. Padding entries (unique_rows[i] < 0) are skipped
-with pl.when so one lowered kernel serves any batch sparsity pattern.
+writing the table row in place (io aliasing) and emitting acc' for the
+caller to scatter back.
 
-Capacity note: the plan arrays ride in scalar-prefetch SMEM (same contract
-as rowwise_adagrad's idx); at production B*F*L the bag list needs chunked
-SMEM staging — tracked in docs/sparse_optimizer.md.
+Layout ("rows on lanes"). Production tables are d=64 wide. TPU tiles are
+(8, 128), and XLA stores an (H, 64) fp32 array with the row axis minor, so
+a row-major (1, 64) row slice cannot be DMA'd at all, and padding the table
+to 128 lanes would copy it on every call. The kernel instead works on the
+free transposed view `table.T` of shape (d, H): row r is lane r % 128 of
+the 128-row tile r // 128. A touched tile is DMA'd into VMEM once, every
+unique row inside it is updated under a lane mask, and the tile is written
+back when the walk leaves it. Rows arrive sorted, so each touched tile
+moves once per grid step. Per-row values (gsum, acc) come in lane-dense
+blocks, and a `pltpu.roll` lines row j's column up with lane r % 128.
+
+SMEM holds only one block of `ROW_BLOCK` row ids per grid step, never the
+whole plan: the plan's capacity at a production batch (B*F*L = 1.7M) is far
+larger than SMEM.
 """
 from __future__ import annotations
 
@@ -32,158 +35,153 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.tpu import MemorySpace, SemaphoreType
 
-from repro.kernels.compat import MemorySpace, SemaphoreType
+LANE = 128
+#: unique rows per grid step; a 1-D int32 SMEM block must be 1024 long to
+#: match XLA's T(1024) layout for the row-id array
+ROW_BLOCK = 1024
 
 
-def _fused_kernel(uniq_ref, off_ref, bag_ref, base_ref, lr_ref, grads_ref,
-                  table_ref, accum_ref, table_out, accum_out, row_vmem,
-                  acc_vmem, gbuf, gacc, sems, *, eps: float):
-    """Grid step (s, i) updates segment s's unique row uniq_ref[s, i].
+def _pad_axis(x: jax.Array, mult: int, axis: int, value=0) -> jax.Array:
+    pad = (-x.shape[axis]) % mult
+    if pad == 0:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths, constant_values=value)
 
-    The grid is (S, C): S per-owner SEGMENTS of C rows each (the routed
-    multi-host update groups a plan's rows by owning capacity shard —
-    docs/cache.md; the single-plan path is simply S=1). Rows are
-    SEGMENT-LOCAL; base_ref[s] rebases them into this table.
 
-    uniq_ref: (S, C), off_ref: (S, C+1) ABSOLUTE positions into bag_ref,
-    bag_ref: (N,), base_ref: (S,) SMEM (scalar prefetch; C may be
-    capacity-trimmed below N); lr_ref: (1,) SMEM; grads_ref: (B*F, D) HBM
-    pooled grads; table_ref/table_out: (H, D) HBM aliased;
-    accum_ref/accum_out: (H, 1) HBM aliased; row_vmem: (1, D); acc_vmem:
-    (1, 1); gbuf: (2, 1, D) f32 double-buffered grad staging; gacc: (1, D)
-    f32 accumulator; sems: 4 DMA semaphores (row, accum, grad slot 0/1).
-    """
-    s = pl.program_id(0)
-    i = pl.program_id(1)
-    ix = uniq_ref[s, i]
+def _apply_kernel(rows_ref, lr_ref, g_ref, acc_ref, table_in, table_out,
+                  acc_out, tile, cur, sem, *, eps: float):
+    """One grid step: ROW_BLOCK unique rows, ascending (-1 = pad).
 
-    @pl.when(ix >= 0)
-    def _():
-        row = base_ref[s] + ix
-        # row + accumulator fetches overlap the bag-gradient stream
-        cp_r = pltpu.make_async_copy(table_ref.at[pl.ds(row, 1)], row_vmem,
-                                     sems.at[0])
-        cp_a = pltpu.make_async_copy(accum_ref.at[pl.ds(row, 1)], acc_vmem,
-                                     sems.at[1])
-        cp_r.start()
-        cp_a.start()
-        gacc[...] = jnp.zeros_like(gacc)
+    rows_ref: (ROW_BLOCK,) SMEM; lr_ref: (1,) SMEM; g_ref: (ROW_BLOCK/128,
+    d, 128) VMEM, row j's gradient sum in lane j % 128 of g_ref[j // 128];
+    acc_ref/acc_out: (ROW_BLOCK/128, 128) VMEM, same lane placement;
+    table_in/table_out: (d, H) HBM, aliased; tile: (d, 128) VMEM, the
+    resident table tile; cur: (1,) SMEM, its index (-1 = none)."""
+    del table_in                       # aliased: read through table_out
+    d = tile.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
 
-        lo = off_ref[s, i]
-        hi = off_ref[s, i + 1]
+    def tile_dma(t, to_hbm):
+        """Move table tile t between HBM and the VMEM tile (blocking)."""
+        hbm = table_out.at[:, pl.ds(pl.multiple_of(t * LANE, LANE), LANE)]
+        src, dst = (tile, hbm) if to_hbm else (hbm, tile)
+        cp = pltpu.make_async_copy(src, dst, sem.at[0])
+        cp.start()
+        cp.wait()
 
-        def grad_copy(j):
-            """DMA descriptor for bag j's grad row (parity-slotted)."""
-            # slot = parity of the ABSOLUTE bag position, so start(j+1)
-            # and wait(j) always address different slots/semaphores; one
-            # descriptor builder serves start AND wait (see embedding_bag)
-            slot = jax.lax.rem(j, 2)
-            return pltpu.make_async_copy(
-                grads_ref.at[pl.ds(bag_ref[j], 1)], gbuf.at[slot],
-                sems.at[2 + slot])
+    cur[0] = -1
+    acc_out[...] = acc_ref[...]
 
-        @pl.when(lo < hi)
+    def body(j, carry):
+        r = rows_ref[j]
+
+        @pl.when(r >= 0)
         def _():
-            grad_copy(lo).start()
+            t = r // LANE
 
-        def body(j, carry):
-            """Accumulate bag j's grad; prefetch bag j+1 behind it."""
-            @pl.when(j + 1 < hi)
+            @pl.when(t != cur[0])
             def _():
-                grad_copy(j + 1).start()    # fetch bag j+1 behind bag j
-            grad_copy(j).wait()
-            # flat-batch bag order (the planner's stable sort) — keeps the
-            # accumulation bit-identical to the legacy scatter-add
-            gacc[...] = gacc[...] + \
-                gbuf[jax.lax.rem(j, 2)].astype(jnp.float32)
-            return carry
+                @pl.when(cur[0] >= 0)
+                def _():
+                    tile_dma(cur[0], True)
+                tile_dma(t, False)
+                cur[0] = t
 
-        jax.lax.fori_loop(lo, hi, body, 0)
-        cp_r.wait()
-        cp_a.wait()
+            a = j // LANE
+            lane_r = r % LANE
+            shift = (lane_r - j % LANE + LANE) % LANE
+            mask = lane == lane_r
+            g = jnp.where(mask, pltpu.roll(g_ref[a], shift, 1), 0.0)
+            acc = pltpu.roll(acc_ref[pl.ds(a, 1), :], shift, 1)
+            acc_new = acc + jnp.sum(g * g, axis=0, keepdims=True) / d
+            upd = lr_ref[0] * g * jax.lax.rsqrt(acc_new + eps)
+            w = tile[...].astype(jnp.float32)
+            tile[...] = jnp.where(mask, w - upd, w).astype(tile.dtype)
+            back = pltpu.roll(acc_new, (LANE - shift) % LANE, 1)
+            acc_out[pl.ds(a, 1), :] = jnp.where(
+                lane == j % LANE, back, acc_out[pl.ds(a, 1), :])
 
-        g = gacc[...]
-        acc_new = acc_vmem[...].astype(jnp.float32) + \
-            jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-        w_new = row_vmem[...].astype(jnp.float32) - \
-            lr_ref[0] * g * jax.lax.rsqrt(acc_new + eps)
+        return carry
 
-        row_vmem[...] = w_new.astype(row_vmem.dtype)
-        acc_vmem[...] = acc_new.astype(acc_vmem.dtype)
+    jax.lax.fori_loop(0, ROW_BLOCK, body, 0)
 
-        cp_wr = pltpu.make_async_copy(row_vmem, table_out.at[pl.ds(row, 1)],
-                                      sems.at[0])
-        cp_wa = pltpu.make_async_copy(acc_vmem, accum_out.at[pl.ds(row, 1)],
-                                      sems.at[1])
-        cp_wr.start()
-        cp_wa.start()
-        cp_wr.wait()
-        cp_wa.wait()
+    @pl.when(cur[0] >= 0)
+    def _():
+        tile_dma(cur[0], True)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def fused_bag_backward_adagrad_segments_kernel(
-        table: jax.Array, accum: jax.Array, seg_rows: jax.Array,
-        seg_offsets: jax.Array, bag_ids: jax.Array, pooled_grads: jax.Array,
-        lr: jax.Array, seg_base: jax.Array, eps: float = 1e-8,
-        interpret: bool = False):
-    """Per-owner-segment generalization: seg_rows (S, C) SEGMENT-LOCAL rows
-    (-1 pads), seg_offsets (S, C+1) ABSOLUTE into bag_ids (N,), seg_base
-    (S,) per-segment row bases (`kernels.sparse_plan.split_plan_by_owner`'s
-    layout); table: (H, D) D % 128 == 0; accum: (H,) or (H, 1) fp32;
-    pooled_grads: (B*F, D) fp32; lr: () fp32. Grid (S, C), rows update in
-    place (io aliasing). Returns (new_table (H, D), new_accum (H, 1))."""
-    h, d = table.shape
-    s, c = seg_rows.shape
-    kernel = functools.partial(_fused_kernel, eps=eps)
+def rowwise_adagrad_apply_kernel(table_t: jax.Array, rows: jax.Array,
+                                 gsum_t: jax.Array, acc: jax.Array,
+                                 lr: jax.Array, eps: float = 1e-8,
+                                 interpret: bool = False):
+    """table_t: (d, H) with H % 128 == 0 (the rows-on-lanes view); rows:
+    (U,) int32 ascending (-1 pads), U % ROW_BLOCK == 0; gsum_t: (U/128, d,
+    128) fp32, row j's gradient sum at [j // 128, :, j % 128]; acc:
+    (U/128, 128) fp32 per-row accumulators, same placement; lr: (1,) fp32.
+    Returns (table_t', acc') — table_t' updated in place."""
+    d, h = table_t.shape
+    u = rows.shape[0]
+    blk = ROW_BLOCK // LANE
     return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(s, c),
-            in_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.SMEM),  # lr
-                pl.BlockSpec(memory_space=MemorySpace.ANY),   # pooled grads
-                pl.BlockSpec(memory_space=MemorySpace.ANY),   # table
-                pl.BlockSpec(memory_space=MemorySpace.ANY),   # accum
-            ],
-            out_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-            ],
-            scratch_shapes=[
-                MemorySpace.VMEM((1, d), table.dtype),
-                MemorySpace.VMEM((1, 1), jnp.float32),
-                MemorySpace.VMEM((2, 1, d), jnp.float32),
-                MemorySpace.VMEM((1, d), jnp.float32),
-                SemaphoreType.DMA((4,)),
-            ],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((h, d), table.dtype),
-                   jax.ShapeDtypeStruct((h, 1), jnp.float32)],
-        input_output_aliases={6: 0, 7: 1},
+        functools.partial(_apply_kernel, eps=eps),
+        grid=(u // ROW_BLOCK,),
+        in_specs=[
+            pl.BlockSpec((ROW_BLOCK,), lambda i: (i,),
+                         memory_space=MemorySpace.SMEM),        # rows
+            pl.BlockSpec(memory_space=MemorySpace.SMEM),         # lr
+            pl.BlockSpec((blk, d, LANE), lambda i: (i, 0, 0)),   # gsum
+            pl.BlockSpec((blk, LANE), lambda i: (i, 0)),         # acc
+            pl.BlockSpec(memory_space=MemorySpace.ANY),          # table
+        ],
+        out_specs=[
+            pl.BlockSpec(memory_space=MemorySpace.ANY),
+            pl.BlockSpec((blk, LANE), lambda i: (i, 0)),
+        ],
+        scratch_shapes=[
+            MemorySpace.VMEM((d, LANE), table_t.dtype),
+            MemorySpace.SMEM((1,), jnp.int32),
+            SemaphoreType.DMA((1,)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((d, h), table_t.dtype),
+                   jax.ShapeDtypeStruct((u // LANE, LANE), jnp.float32)],
+        input_output_aliases={4: 0},
         interpret=interpret,
-    )(seg_rows, seg_offsets, bag_ids, seg_base.astype(jnp.int32),
-      jnp.asarray(lr, jnp.float32).reshape(1),
-      pooled_grads.astype(jnp.float32), table,
-      accum.reshape(h, 1).astype(jnp.float32))
+        name="rowwise_adagrad_apply",
+    )(rows, lr, gsum_t, acc, table_t)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def fused_bag_backward_adagrad_kernel(table: jax.Array, accum: jax.Array,
-                                      unique_rows: jax.Array,
-                                      bag_offsets: jax.Array,
-                                      bag_ids: jax.Array,
-                                      pooled_grads: jax.Array,
-                                      lr: jax.Array, eps: float = 1e-8,
-                                      interpret: bool = False):
-    """table: (H, D) D % 128 == 0; accum: (H,) or (H, 1) fp32; plan arrays
-    from kernels/sparse_plan.py (int32); pooled_grads: (B*F, D) fp32;
-    lr: () fp32. Returns (new_table (H, D), new_accum (H, 1)) updated in
-    place (io aliasing). The ONE-segment case of the segmented kernel
-    above (a plan's bag_offsets are already absolute when unsegmented)."""
-    return fused_bag_backward_adagrad_segments_kernel(
-        table, accum, unique_rows[None, :], bag_offsets[None, :], bag_ids,
-        pooled_grads, lr, jnp.zeros((1,), jnp.int32), eps=eps,
+def rowwise_adagrad_apply(table: jax.Array, accum: jax.Array,
+                          rows: jax.Array, gsum: jax.Array, lr,
+                          eps: float = 1e-8, interpret: bool = False
+                          ) -> tuple[jax.Array, jax.Array]:
+    """Apply row-wise AdaGrad to `rows` of `table` through the kernel.
+
+    table: (H, d); accum: (H,) fp32; rows: (U,) int32 sorted ascending
+    among its valid (>= 0) entries, each valid row at most once; gsum:
+    (U, d) fp32 aggregated gradients aligned with `rows`. Returns (table',
+    accum'). The table moves through the kernel as its (d, H) view, so a
+    height that is a multiple of 128 (every placement plan's,
+    core/placement.py) is updated in place with no copy; other heights are
+    padded for the call."""
+    h, d = table.shape
+    valid = rows >= 0
+    safe = jnp.where(valid, rows, 0)
+    acc_u = jnp.where(valid, accum[safe], 0.0)
+    rows_p = _pad_axis(rows.astype(jnp.int32), ROW_BLOCK, 0, value=-1)
+    u = rows_p.shape[0]
+    g = _pad_axis(gsum.astype(jnp.float32), ROW_BLOCK, 0)
+    gsum_t = g.reshape(u // LANE, LANE, d).transpose(0, 2, 1)
+    acc_p = _pad_axis(acc_u, ROW_BLOCK, 0).reshape(u // LANE, LANE)
+    table_t = _pad_axis(table.T, LANE, 1)
+    new_t, acc_new = rowwise_adagrad_apply_kernel(
+        table_t, rows_p, gsum_t, acc_p,
+        jnp.asarray(lr, jnp.float32).reshape(1), eps=eps,
         interpret=interpret)
+    drop = jnp.where(valid, rows, h)                  # h = dropped
+    new_accum = accum.at[drop].set(acc_new.reshape(u)[:rows.shape[0]],
+                                   mode="drop")
+    return new_t[:, :h].T, new_accum

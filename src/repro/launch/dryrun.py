@@ -389,8 +389,7 @@ def run_cell(arch: str, shape: Shape, multi_pod: bool,
                                 + mem.temp_size_in_bytes
                                 - mem.alias_size_in_bytes),
         }
-        from repro.compat import cost_analysis_dict
-        xla_cost = cost_analysis_dict(compiled)
+        xla_cost = compiled.cost_analysis()
         rec["xla_flops_uncorrected"] = xla_cost.get("flops", -1.0)
         if skip_collectives:
             coll_by_type, coll_total = {}, 0.0

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import DLRMConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _serve_lm(cfg, args) -> None:
@@ -50,18 +51,38 @@ def _serve_lm(cfg, args) -> None:
         print(f"  req {uid}: {done[uid][:8]}...")
 
 
-def _serve_dlrm(cfg, args) -> None:
+def dlrm_serve_engine(cfg, cache_rows: int | None = None, seed: int = 0,
+                      **engine_kw):
+    """The DLRM serve tier as the launcher builds it: random weights from
+    `seed`, a cached embedding tier of `cache_rows` slots (None: the
+    placement plan's size) and a `DLRMServeEngine` (`engine_kw` are its
+    options). The table is generated on the host, so the engine's
+    capacity tier holds the only device copy of it. Returns (ebc, params,
+    engine); params["emb"]["mega"] is that host table."""
     from repro.core.cache import CachedEmbeddingBagCollection
     from repro.core.dlrm import dlrm_param_specs
     from repro.core.embedding import EmbeddingBagCollection
-    from repro.data.synthetic import make_dlrm_batch
     from repro.nn.params import init_params
-    from repro.serve import DLRMServeEngine, ServeRequest
+    from repro.serve import DLRMServeEngine
 
     ebc = EmbeddingBagCollection.build(cfg, n_shards=1,
                                        strategy="replicated")
-    params = init_params(dlrm_param_specs(cfg, ebc), jax.random.PRNGKey(0))
-    cc = CachedEmbeddingBagCollection.build(cfg, cache_rows=args.cache_rows)
+    specs = dlrm_param_specs(cfg, ebc)
+    params = init_params({"bottom": specs["bottom"], "top": specs["top"]},
+                         jax.random.PRNGKey(seed))
+    spec = specs["emb"]["mega"]
+    mega = np.random.default_rng(seed).standard_normal(spec.shape,
+                                                       dtype=np.float32)
+    mega *= np.float32(spec.scale)
+    params["emb"] = {"mega": mega}
+    cc = CachedEmbeddingBagCollection.build(cfg, cache_rows=cache_rows)
+    return ebc, params, DLRMServeEngine(params, cfg, cc, **engine_kw)
+
+
+def _serve_dlrm(cfg, args) -> None:
+    from repro.data.synthetic import make_dlrm_batch
+    from repro.serve import ServeRequest
+
     injector = retry = None
     if args.chaos:
         from repro.train.fault_tolerance import FaultInjector, RetryPolicy
@@ -69,9 +90,9 @@ def _serve_dlrm(cfg, args) -> None:
             args.chaos_seed, args.requests,
             sites=("serve.fetch", "serve.admit"), n_faults=3)
         retry = RetryPolicy(max_retries=1, backoff_s=1e-4)
-    engine = DLRMServeEngine(params, cfg, cc, max_queue=args.max_queue,
-                             max_batch=args.max_batch, injector=injector,
-                             retry=retry)
+    ebc, _, engine = dlrm_serve_engine(
+        cfg, args.cache_rows, max_queue=args.max_queue,
+        max_batch=args.max_batch, injector=injector, retry=retry)
 
     t0 = time.time()
     for uid in range(args.requests):
@@ -115,7 +136,9 @@ def main():
     ap.add_argument("--max-batch", type=int, default=16,
                     help="engine batch slots (examples per dispatch)")
     ap.add_argument("--max-queue", type=int, default=64)
-    ap.add_argument("--cache-rows", type=int, default=256)
+    ap.add_argument("--cache-rows", type=int, default=None,
+                    help="device cache slots (default: the placement "
+                         "plan's size for the arch)")
     ap.add_argument("--burst", type=int, default=4,
                     help="requests submitted per engine step (offered load)")
     ap.add_argument("--zipf-alpha", type=float, default=1.05)
@@ -124,6 +147,7 @@ def main():
     ap.add_argument("--chaos-seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
     if isinstance(cfg, DLRMConfig):

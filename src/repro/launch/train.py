@@ -1,8 +1,10 @@
 """Training launcher (end-to-end driver, deliverable b).
 
-Runs REAL training on the available devices (CPU here; the same script runs
-on a pod by virtue of pjit + make_production_mesh). For CPU runs use a smoke
-arch: `python -m repro.launch.train --arch stablelm-1.6b --smoke --steps 50`.
+Runs REAL training on one device (a TPU chip, or the CPU with
+JAX_PLATFORMS=cpu). For CPU runs use a smoke arch:
+`python -m repro.launch.train --arch stablelm-1.6b --smoke --steps 50`.
+`dlrm_job` + `train_loop` are the pieces `chip_smoke.py` drives at
+dlrm-m2's published widths.
 
 Features exercised: sharded params, data pipeline with host prefetch,
 AdamW/AdaGrad split, checkpoint/restore (resumable), preemption handling,
@@ -22,6 +24,7 @@ from repro.core.dlrm import dlrm_param_specs
 from repro.core.embedding import EmbeddingBagCollection
 from repro.data.pipeline import ShardedLoader
 from repro.data.synthetic import make_dlrm_batch, make_lm_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.lm import lm_param_specs
 from repro.nn.params import init_params
 from repro.nn.sharding import TRAIN_RULES
@@ -34,6 +37,76 @@ from repro.train.fault_tolerance import (FaultInjector, PreemptionHandler,
                                          save_train_state)
 from repro.train.steps import (build_dlrm_train_step, build_lm_train_step,
                                dlrm_init_state)
+
+
+def dlrm_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection, opt,
+                    use_kernel: bool | None = None):
+    """The launcher's jitted DLRM step (see `dlrm_job`)."""
+    return jax.jit(build_dlrm_train_step(cfg, ebc, opt,
+                                         sparse_apply="sparse",
+                                         use_kernel=use_kernel),
+                   donate_argnums=(0, 1))
+
+
+def dlrm_job(cfg: DLRMConfig, batch: int, key,
+             use_kernel: bool | None = None):
+    """The DLRM half of the launcher: collection, params, optimizer state
+    factory, the jitted step and the batch generator for `ShardedLoader`.
+
+    The launcher runs on one device (it builds no mesh), so the step takes
+    the unique-row apply — O(lookups) rows per step, not the O(table
+    height) dense scatter — and donates params and state so the table is
+    updated in place. `use_kernel=False` builds the same step on the jnp
+    reference ops. Returns (ebc, specs, params, fresh_state, step_fn,
+    gen)."""
+    ebc = EmbeddingBagCollection.build(cfg, n_shards=1)
+    specs = dlrm_param_specs(cfg, ebc)
+    params = init_params(specs, key)
+    opt = adagrad(0.01)
+
+    def fresh_state(p):
+        return dlrm_init_state(ebc, opt, p)
+
+    step_fn = dlrm_train_step(cfg, ebc, opt, use_kernel)
+
+    def gen(step, seed):
+        raw = make_dlrm_batch(cfg, batch, step, seed)
+        raw["idx"] = np.asarray(ebc.offset_indices(
+            jnp.asarray(raw["idx"])))
+        return raw
+
+    return ebc, specs, params, fresh_state, step_fn, gen
+
+
+def train_loop(step_fn, params, state, pipeline, n_steps: int, *,
+               start: int = 0, log_every: int = 10, save=None,
+               ckpt_every: int = 0, preempt=None, straggler=None):
+    """The launcher's training loop: pull (step, batch) from `pipeline`,
+    run `step_fn`, checkpoint through `save(step, params, state)` every
+    `ckpt_every` steps (none when `save` is None), stop early on
+    preemption. Returns (params, state, losses, last_step)."""
+    live = {"params": params, "state": state}
+    losses = []
+
+    def one_step(step):
+        _, batch = next(pipeline)
+        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        live["params"], live["state"], metrics = step_fn(
+            live["params"], live["state"], batch,
+            jnp.asarray(step, jnp.int32))
+        loss = float(metrics["loss"])
+        losses.append(loss)
+        if step % log_every == 0:
+            print(f"step {step:5d} loss {loss:.4f}")
+
+    def checkpoint(step):
+        if save is not None:
+            save(step, live["params"], live["state"])
+
+    last = run_resilient_loop(one_step, n_steps, checkpoint,
+                              ckpt_every or max(n_steps, 1), preempt,
+                              straggler, start_step=start)
+    return live["params"], live["state"], losses, last
 
 
 def main():
@@ -60,6 +133,7 @@ def main():
                     help="number of scheduled faults over the run")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
     is_dlrm = isinstance(cfg, DLRMConfig)
@@ -78,21 +152,8 @@ def main():
     straggler = StragglerDetector()
 
     if is_dlrm:
-        ebc = EmbeddingBagCollection.build(cfg, n_shards=1)
-        specs = dlrm_param_specs(cfg, ebc)
-        params = init_params(specs, key)
-        opt = adagrad(0.01)
-
-        def fresh_state(p):
-            return dlrm_init_state(ebc, opt, p)
-
-        step_fn = jax.jit(build_dlrm_train_step(cfg, ebc, opt))
-
-        def gen(step, seed):
-            raw = make_dlrm_batch(cfg, args.batch, step, seed)
-            raw["idx"] = np.asarray(ebc.offset_indices(
-                jnp.asarray(raw["idx"])))
-            return raw
+        _, specs, params, fresh_state, step_fn, gen = dlrm_job(
+            cfg, args.batch, key)
     else:
         specs = lm_param_specs(cfg)
         params = init_params(specs, key)
@@ -122,24 +183,13 @@ def main():
         start = ckpt.latest_step()
         print(f"resumed from step {start}")
 
-    losses = []
-
-    def one_step(step):
-        nonlocal params, state
-        _, batch = next(pipeline)
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        params, state, metrics = step_fn(params, state, batch,
-                                         jnp.asarray(step, jnp.int32))
-        loss = float(metrics["loss"])
-        losses.append(loss)
-        if step % args.log_every == 0:
-            print(f"step {step:5d} loss {loss:.4f}")
-
-    def save(step):
+    def save(step, params, state):
         ckpt.save(step, {"params": params, "state": state}, async_=True)
 
-    last = run_resilient_loop(one_step, args.steps, save, args.ckpt_every,
-                              preempt, straggler, start_step=start)
+    _, _, losses, last = train_loop(
+        step_fn, params, state, pipeline, args.steps, start=start,
+        log_every=args.log_every, save=save, ckpt_every=args.ckpt_every,
+        preempt=preempt, straggler=straggler)
     ckpt.wait()
     pipeline.close()
     print(f"done at step {last}; loss {losses[0]:.4f} -> {losses[-1]:.4f}; "

@@ -1,4 +1,4 @@
-"""Dense optimizers (the embedding path uses kernels/rowwise_adagrad).
+"""Dense optimizers (the embedding path uses kernels/sparse_update).
 
 Functional, optax-shaped but dependency-free:
   opt = adamw(lr=...); state = opt.init(params)

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cache import StaleRowSnapshot, _fetch_guard
+from repro.kernels import cache_ops
 from repro.nn.sharding import SERVE_RULES, LogicalRules
 
 #: `Overloaded.reason` values
@@ -398,8 +399,14 @@ class DLRMServeEngine:
                 fresh = rows[~self.snapshot.seen[rows]]
                 if len(fresh):
                     slots = self.state.row_slot[fresh]
-                    self.snapshot.record(
-                        fresh, np.asarray(self.state.cache[slots]))
+                    # the tier's own row fetch: on TPU the row-move kernel,
+                    # which reads the cache in place
+                    vals, _ = cache_ops.cache_fetch(
+                        self.state.cache, self.state.cache_accum,
+                        jnp.asarray(slots, jnp.int32),
+                        use_kernel=self.cc.use_kernel,
+                        interpret=self.cc.interpret)
+                    self.snapshot.record(fresh, np.asarray(vals))
         if degraded:
             table, local = self._stale_local(idx)
         probs = np.asarray(

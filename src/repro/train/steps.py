@@ -117,9 +117,15 @@ def build_dlrm_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
                           dense_opt: Optimizer, sparse_lr: float = 0.05,
                           sparse_eps: float = 1e-8, interpret: bool = False,
                           rules: LogicalRules = TRAIN_RULES,
-                          sparse_apply: str = "dense") -> Callable:
+                          sparse_apply: str = "dense",
+                          use_kernel: bool | None = None) -> Callable:
     """Returns step(params, state, batch, step_idx) -> (params, state,
     metrics) where state = {"dense": dense_opt_state, "accum": (rows,) f32}.
+
+    `use_kernel` picks the Pallas kernels (None: on TPU) or, with False, the
+    jnp reference ops (kernels/ref.py) for the embedding gather, the
+    interaction and the unique-row apply — the float32 path a chip run is
+    checked against.
 
     sparse_apply:
       "dense"  — scatter-add over the (sharded) full row space; right for
@@ -139,7 +145,7 @@ def build_dlrm_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
         per-lookup broadcast + full-width dedup sort."""
         return kernel_ops.fused_sparse_backward(
             mega, accum, idx, g_pooled, sparse_lr, sparse_eps, plan=plan,
-            interpret=interpret)
+            use_kernel=use_kernel, interpret=interpret)
 
     def sparse_update_shardmap(mega, accum, idx, g_pooled, plan=None):
         """shard_map PS-side aggregation: each (model, data) shard buckets
@@ -153,7 +159,6 @@ def build_dlrm_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
         traffic — EXPERIMENTS.md Perf, dlrm-m3)."""
         from jax.sharding import PartitionSpec as SP
 
-        from repro.compat import shard_map
         from repro.nn.sharding import _live_mesh
         mesh = _live_mesh()
         h, d = mega.shape
@@ -189,7 +194,7 @@ def build_dlrm_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
                                            0.0).astype(mega_sh.dtype)
             return new_mega, acc_new
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(SP(model_axis, None), SP(model_axis),
                       SP(batch_axes, None, None), SP(batch_axes, None, None)),
@@ -232,7 +237,7 @@ def build_dlrm_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
 
     def step(params, state, batch, step_idx):
         loss, g_dense, (idx, g_pooled) = dlrm_grads(
-            params, batch, cfg, ebc, interpret, rules)
+            params, batch, cfg, ebc, interpret, rules, use_kernel)
         new_dense, new_dense_state = dense_opt.apply(
             {"bottom": params["bottom"], "top": params["top"]},
             g_dense, state["dense"], step_idx)
@@ -488,15 +493,13 @@ def _build_multihost_cached_step(cfg: DLRMConfig, mc,
         if mesh is not None:
             from jax.sharding import PartitionSpec as SP
 
-            from repro.compat import shard_map
-
             def owner_update(cap_sh, acc_sh, rows_sh, offs_sh, bags, g2):
                 return kernel_ops.fused_sparse_backward_segments(
                     cap_sh, acc_sh, rows_sh, offs_sh, bags, g2, sparse_lr,
                     eps=sparse_eps, use_kernel=mc.use_kernel,
                     interpret=interpret)
 
-            new_cap, new_acc = shard_map(
+            new_cap, new_acc = jax.shard_map(
                 owner_update, mesh=mesh,
                 in_specs=(SP(host_axis, None), SP(host_axis),
                           SP(host_axis, None), SP(host_axis, None),
@@ -771,14 +774,12 @@ def build_tablewise_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
         if mesh is not None:
             from jax.sharding import PartitionSpec as SP
 
-            from repro.compat import shard_map
-
             def owner_update(mega_sh, acc_sh, rows_sh, offs_sh, bags, g2):
                 return kernel_ops.fused_sparse_backward_segments(
                     mega_sh, acc_sh, rows_sh, offs_sh, bags, g2, sparse_lr,
                     eps=sparse_eps, interpret=interpret)
 
-            new_mega, new_accum = shard_map(
+            new_mega, new_accum = jax.shard_map(
                 owner_update, mesh=mesh,
                 in_specs=(SP(model_axis, None), SP(model_axis),
                           SP(model_axis, None), SP(model_axis, None),

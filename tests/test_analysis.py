@@ -28,8 +28,7 @@ def test_stablehlo_flops_match_xla_loop_free():
         jax.ShapeDtypeStruct((128, 256), jnp.float32),
         jax.ShapeDtypeStruct((256, 256), jnp.float32))
     ours = StableHloAnalysis(lowered.as_text()).cost()
-    from repro.compat import cost_analysis_dict
-    xla = cost_analysis_dict(lowered.compile())
+    xla = lowered.compile().cost_analysis()
     assert ours.mxu_flops == pytest.approx(xla["flops"], rel=0.01)
 
 

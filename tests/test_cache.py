@@ -16,7 +16,8 @@ from repro.configs import get_smoke_config
 from repro.core.cache import CachedEmbeddingBagCollection
 from repro.core.dlrm import dlrm_param_specs
 from repro.core.embedding import EmbeddingBagCollection
-from repro.core.placement import CACHED_ROW_META_BYTES, plan_placement
+from repro.core.placement import (CACHED_ROW_META_BYTES, ROW_TILE,
+                                 plan_placement)
 from repro.data.pipeline import DataPipeline, dedup_indices_hook
 from repro.data.synthetic import bounded_zipf_rows, make_dlrm_batch
 from repro.kernels import cache_ops, ops, ref
@@ -24,11 +25,6 @@ from repro.nn.params import init_params
 from repro.optim.optimizers import adagrad
 from repro.train.steps import (build_cached_dlrm_train_step,
                                cached_dlrm_init_state)
-
-# exercised on BOTH jax floors: this module drives the compat-shim surfaces
-# (Pallas memory spaces, shard_map, kernel interpret paths) — see pyproject
-# markers and the CI jax-floor leg
-pytestmark = pytest.mark.compat
 
 
 @pytest.fixture(scope="module")
@@ -58,12 +54,13 @@ def test_plan_cached_host_capacity_math():
     plan = plan_placement([5000, 7000, 100], [8, 2, 30], d, 4, budget,
                           itemsize=itemsize, strategy="cached_host")
     assert plan.strategy == "cached_host"
-    assert plan.cache_rows % 8 == 0
+    assert plan.cache_rows % ROW_TILE == 0
+    assert plan.total_rows % ROW_TILE == 0
     assert plan.cache_rows <= plan.total_rows
     row_bytes = d * itemsize + CACHED_ROW_META_BYTES
     assert plan.cache_rows * row_bytes <= budget
-    # one more row row-group would overflow the budget
-    assert (plan.cache_rows + 8) * row_bytes > budget
+    # one more row tile would overflow the budget
+    assert (plan.cache_rows + ROW_TILE) * row_bytes > budget
     # capacity tier is replicated (host-resident) — no model-axis sharding
     assert plan.pspec == jax.sharding.PartitionSpec(None, None)
 

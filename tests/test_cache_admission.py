@@ -25,10 +25,6 @@ if HAS_HYPOTHESIS:
     from hypothesis import given, settings
     from hypothesis import strategies as st
 
-# exercised on BOTH jax floors (the CI 0.4.37 leg runs `-m compat`): the
-# chunked transfer path drives the kernels/compat.py shim surfaces
-pytestmark = pytest.mark.compat
-
 
 @pytest.fixture(scope="module")
 def cfg():

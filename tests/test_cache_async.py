@@ -24,11 +24,6 @@ from repro.train.steps import (build_async_cached_dlrm_train_step,
                                build_cached_dlrm_train_step,
                                cached_dlrm_init_state)
 
-# exercised on BOTH jax floors: this module drives the compat-shim surfaces
-# (Pallas memory spaces, shard_map, kernel interpret paths) — see pyproject
-# markers and the CI jax-floor leg
-pytestmark = pytest.mark.compat
-
 
 @pytest.fixture(scope="module")
 def cfg():

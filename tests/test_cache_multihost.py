@@ -40,7 +40,6 @@ from repro.train.steps import (build_cached_dlrm_train_step,
                                build_multihost_cached_train_step,
                                cached_dlrm_init_state, dlrm_init_state)
 
-pytestmark = pytest.mark.compat
 
 # ---------------------------------------------------------------------------
 # corpus shared by the splitting tests

@@ -24,10 +24,6 @@ from repro.launch.analysis import (embedding_forward_traffic,
 from repro.nn.params import init_params
 from repro.optim import adagrad
 
-# exercised on BOTH jax floors: this module drives the compat-shim surfaces
-# (Pallas memory spaces, shard_map, kernel interpret paths) — see pyproject
-# markers and the CI jax-floor leg
-pytestmark = pytest.mark.compat
 
 # ---------------------------------------------------------------------------
 # index corpora: the ISSUE's stress patterns (2D bag layout)

@@ -8,10 +8,6 @@ import pytest
 from repro.kernels import ops, ref
 
 
-# exercised on BOTH jax floors: this module drives the compat-shim surfaces
-# (Pallas memory spaces, shard_map, kernel interpret paths) — see pyproject
-# markers and the CI jax-floor leg
-pytestmark = pytest.mark.compat
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 

@@ -7,13 +7,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
-# exercised on BOTH jax floors: these subprocess tests drive shard_map
-# and mesh construction through the compat shims — see pyproject markers
-# and the CI jax-floor leg
-pytestmark = pytest.mark.compat
-
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -22,7 +15,8 @@ def _run(body: str) -> str:
     code = (
         "import os\n"
         "os.environ['XLA_FLAGS'] = "
-        "'--xla_force_host_platform_device_count=8'\n" + body)
+        "'--xla_force_host_platform_device_count=8'\n"
+        "from repro.launch.mesh import make_test_mesh\n" + body)
     env = dict(os.environ, PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=500)
@@ -37,7 +31,7 @@ from repro.configs import get_smoke_config
 from repro.core.embedding import EmbeddingBagCollection
 from repro.nn.params import init_params
 cfg = dataclasses.replace(get_smoke_config("dlrm-m1"), placement="row_wise")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 ebc = EmbeddingBagCollection.build(cfg, n_shards=4)
 params = init_params(ebc.param_specs(), jax.random.PRNGKey(0))
 rng = np.random.RandomState(0)
@@ -65,7 +59,7 @@ from repro.data import make_dlrm_batch
 cfg = dataclasses.replace(get_smoke_config("dlrm-m1"),
                           placement="row_wise", lookup_impl="psum")
 cfg_ref = dataclasses.replace(cfg, lookup_impl="gather")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 ebc = EmbeddingBagCollection.build(cfg, n_shards=4)
 params = init_params(dlrm_param_specs(cfg, ebc), jax.random.PRNGKey(0))
 opt = adagrad(0.05)
@@ -103,7 +97,7 @@ from repro.train.steps import build_lm_train_step
 from repro.data.synthetic import lm_batch_specs
 
 cfg = get_smoke_config("stablelm-1.6b")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 for name, rules in [("train", TRAIN_RULES), ("fsdp", FSDP_RULES),
                     ("zero_dp", ZERO_DP_RULES)]:
     specs = lm_param_specs(cfg)
@@ -133,7 +127,7 @@ def test_easgd_pod_axis_semantics():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.optim.easgd import easgd_init, easgd_sync
-mesh = jax.make_mesh((4, 2), ("pod", "model"))
+mesh = make_test_mesh((4, 2), ("pod", "model"))
 state = easgd_init({"w": jnp.arange(6.0)}, n_replicas=4)
 state = state._replace(replicas={"w": jnp.stack(
     [jnp.arange(6.0) + i for i in range(4)])})
@@ -161,8 +155,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.train.checkpoint import CheckpointManager
 
 tmp = tempfile.mkdtemp()
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_a = make_test_mesh((4, 2), ("data", "model"))
+mesh_b = make_test_mesh((2, 4), ("data", "model"))
 w = jnp.arange(64.0).reshape(8, 8)
 tree = {"w": jax.device_put(w, NamedSharding(mesh_a, P("data", "model"))),
         "b": jnp.arange(8.0, dtype=jnp.bfloat16)}
@@ -204,7 +198,7 @@ cfg = get_smoke_config("dlrm-m1")
 ebc = EmbeddingBagCollection.build(cfg, n_shards=1, strategy="replicated")
 params = init_params(dlrm_param_specs(cfg, ebc), jax.random.PRNGKey(0))
 opt = adagrad(0.01)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_test_mesh((8,), ("data",))
 N, B = 4, 16
 batches = []
 for t in range(N):
@@ -253,11 +247,10 @@ def test_pallas_embedding_bag_inside_shard_map():
     the per-shard PS lookup path on real TPUs."""
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.kernels import ops, ref
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_test_mesh((4,), ("model",))
 H, D, B, L = 64, 16, 8, 5          # 16 rows per shard
 rng = np.random.RandomState(0)
 table = jnp.asarray(rng.randn(H, D), jnp.float32)
@@ -274,7 +267,7 @@ def local(table_sh, idx_rep):
 with mesh:
     # check_vma=False: pallas_call's out_shape carries no varying-axes
     # metadata (kernel outputs are shard-local by construction)
-    got = jax.jit(shard_map(local, mesh=mesh,
+    got = jax.jit(jax.shard_map(local, mesh=mesh,
                             in_specs=(P("model", None), P(None, None)),
                             out_specs=P(None, None),
                             check_vma=False))(table, idx)

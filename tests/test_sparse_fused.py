@@ -26,10 +26,6 @@ from repro.train.steps import build_dlrm_train_step, dlrm_init_state
 
 from conftest import requires_hypothesis  # noqa: E402  (pytest test path)
 
-# exercised on BOTH jax floors: this module drives the compat-shim surfaces
-# (Pallas memory spaces, shard_map, kernel interpret paths) — see pyproject
-# markers and the CI jax-floor leg
-pytestmark = pytest.mark.compat
 
 # ---------------------------------------------------------------------------
 # index corpora: the ISSUE's stress patterns
@@ -356,11 +352,12 @@ from repro.core.embedding import EmbeddingBagCollection
 from repro.data.synthetic import make_dlrm_batch
 from repro.nn.params import init_params
 from repro.optim import adagrad
+from repro.launch.mesh import make_test_mesh
 from repro.train.steps import build_dlrm_train_step, dlrm_init_state
 
 cfg = dataclasses.replace(get_smoke_config("dlrm-m1"),
                           placement="row_wise", lookup_impl="psum")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 ebc = EmbeddingBagCollection.build(cfg, n_shards=4)
 params = init_params(dlrm_param_specs(cfg, ebc), jax.random.PRNGKey(0))
 opt = adagrad(0.05)

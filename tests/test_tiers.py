@@ -30,7 +30,6 @@ from repro.train.steps import (build_async_cached_dlrm_train_step,
                                build_multihost_cached_train_step,
                                cached_dlrm_init_state)
 
-pytestmark = pytest.mark.compat
 
 if HAS_HYPOTHESIS:
     from hypothesis import given, settings

@@ -11,14 +11,17 @@ one 16 GB chip, so every table height is scaled by TABLE_SCALE (printed as
   train  `launch/train.py`'s `dlrm_job` + `train_loop` for TRAIN_STEPS
          steps of TRAIN_BATCH examples, then the same steps on the jnp
          reference ops (`use_kernel=False`, kernels/ref.py) from the same
-         seed: per-step losses and every touched table row must agree.
+         seed: per-step losses must agree, and every touched table row
+         must lie as close to the reference as a one-ulp nudge of the
+         reference's own weights leaves it (see `check_rows`).
   serve  `launch/serve.py`'s `dlrm_serve_engine` (cache sized by the
          placement plan) answers SERVE_REQUESTS requests; every answered
          probability must agree with the dense forward over a host gather
          of the same table.
 
 Four chips. `build_tablewise_train_step` on a (data=1, model=4) mesh: at
-the one-chip height against the single-device step, then at m2's full
+the one-chip height against the single-device step (same checks), then
+at m2's full
 published heights, with the table initialised on its owners.
 
 Earlier lines report the implementation of each layer (Pallas kernel or
@@ -53,6 +56,13 @@ SEED = 0
 LOSS_RTOL = 1e-4
 ROW_RTOL, ROW_ATOL = 1e-4, 1e-5
 PROB_ATOL = 1e-3
+# Touched rows after TRAIN_STEPS steps are held to the reference's own fp32
+# sensitivity instead of a fixed tolerance: a row-wise AdaGrad step moves a
+# row by lr * g * rsqrt(mean(g^2) + eps), which turns the last-bit
+# differences of any reordered sum into ~1e-4 row differences wherever g is
+# small. The floor is the gap between the reference and the reference
+# rerun with its bottom-MLP weights nudged by one ulp (`nudge_bottom`); a
+# wrong row or lane is off by a whole update (~lr = 0.05), far above it.
 
 #: Pallas kernels by the name they carry in a compiled program
 KERNELS = ("move_rows", "rowwise_adagrad_apply", "dot_interaction")
@@ -93,9 +103,35 @@ def touched_rows(gen, steps: int) -> np.ndarray:
     return np.unique(idx[idx >= 0]).astype(np.int32)
 
 
-def train_run(cfg, use_kernel, rows_of, label: str):
-    """One launcher run of TRAIN_STEPS steps. Returns (losses, table rows
-    and accumulators at `rows_of(gen)`, found kernels, rows)."""
+def nudge_bottom(params: dict) -> dict:
+    """params with every bottom-MLP weight moved up by one ulp."""
+    import jax
+    import jax.numpy as jnp
+    return {**params, "bottom": jax.tree.map(
+        lambda w: jnp.nextafter(w, jnp.inf), params["bottom"])}
+
+
+def check_rows(what: str, got, want, nudged) -> None:
+    """`got` must match `want` (the reference) within ROW_RTOL/ROW_ATOL,
+    or else differ from it by no more than `nudged` (the reference from a
+    one-ulp nudge) does, both in the largest difference and in the count of
+    elements outside ROW_RTOL/ROW_ATOL."""
+    def gap(x):
+        out = ~np.isclose(x, want, rtol=ROW_RTOL, atol=ROW_ATOL)
+        return float(np.abs(x - want).max()), int(out.sum())
+
+    (d, n), (fd, fn) = gap(got), gap(nudged)
+    log(f"{what}: max_abs_diff={d:.3e} outside_tol={n} of {want.size}; "
+        f"one-ulp floor max_abs_diff={fd:.3e} outside_tol={fn}")
+    if n and (d > fd or n > fn):
+        raise SystemExit(f"{what} differ from the reference by more than "
+                         "fp32 rounding moves it")
+
+
+def train_run(cfg, use_kernel, rows_of, label: str, nudge: bool = False):
+    """One launcher run of TRAIN_STEPS steps (`nudge`: from `nudge_bottom`
+    params). Returns (losses, table rows and accumulators at
+    `rows_of(gen)`, found kernels, rows)."""
     import jax
     import jax.numpy as jnp
 
@@ -105,6 +141,8 @@ def train_run(cfg, use_kernel, rows_of, label: str):
 
     _, _, params, fresh_state, step_fn, gen = dlrm_job(
         cfg, TRAIN_BATCH, jax.random.PRNGKey(SEED), use_kernel=use_kernel)
+    if nudge:
+        params = nudge_bottom(params)
     state = fresh_state(params)
     first = {k: jnp.asarray(v) for k, v in gen(0, SEED).items()}
     t0 = time.perf_counter()
@@ -158,17 +196,19 @@ def train_phase(cfg, dev) -> None:
         f"kernels={sorted(r_found) or 'none'}")
     if r_found:
         raise SystemExit(f"reference step ran kernels {r_found}")
-    for k, (a, b) in enumerate(zip(losses, r_losses)):
+    n_losses, n_vals, n_acc, _, _ = train_run(cfg, False, rows_of,
+                                              "reference, one-ulp nudge",
+                                              nudge=True)
+    for k, (a, b, c) in enumerate(zip(losses, r_losses, n_losses)):
         log(f"train step {k}: loss kernel={a!r} reference={b!r} "
-            f"rel_diff={abs(a - b) / abs(b):.3e}")
-    log(f"train touched_rows={len(rows)} max_abs_diff rows="
-        f"{np.abs(vals - r_vals).max():.3e} accum="
-        f"{np.abs(acc - r_acc).max():.3e}")
+            f"rel_diff={abs(a - b) / abs(b):.3e} (one-ulp nudge "
+            f"{abs(c - b) / abs(b):.3e})")
     if not np.all(np.isfinite(losses)):
         raise SystemExit("non-finite training loss")
     np.testing.assert_allclose(losses, r_losses, rtol=LOSS_RTOL)
-    np.testing.assert_allclose(vals, r_vals, rtol=ROW_RTOL, atol=ROW_ATOL)
-    np.testing.assert_allclose(acc, r_acc, rtol=ROW_RTOL, atol=ROW_ATOL)
+    log(f"train touched_rows={len(rows)}")
+    check_rows("train rows", vals, r_vals, n_vals)
+    check_rows("train accum", acc, r_acc, n_acc)
     log("train: kernel path matches the reference")
 
 
@@ -202,26 +242,32 @@ def serve_phase(cfg, dev) -> None:
     engine.run()
     log(f"serve requests={SERVE_REQUESTS} wall_s="
         f"{time.perf_counter() - t0:.3f} (includes compiles)")
-    found = set()
+    # each layer is read from its own compiled program: the exchange from
+    # the cache-exchange compile, the rest from the engine's forward
     n = 1 << 10
     args = (engine.state.capacity, engine.state.cache,
             engine.state.cap_accum, engine.state.cache_accum,
             engine.state.freq) + (jnp.full((n,), -1, jnp.int32),) * 3 + (
         jnp.zeros((n,), jnp.float32),)
-    found |= kernels_in(cache_ops._exchange_kernel_jit.lower(
+    found_x = kernels_in(cache_ops._exchange_kernel_jit.lower(
         *args, interpret=False).compile())
-    found |= kernels_in(engine._fwd.lower(
+    found_f = kernels_in(engine._fwd.lower(
         engine.dense, engine.state.cache,
         jnp.zeros((SERVE_MAX_BATCH, cfg.n_dense_features), jnp.float32),
         jnp.zeros((SERVE_MAX_BATCH,) + reqs[0][2].shape[1:],
                   jnp.int32)).compile())
-    report_layers("serve", found, [
-        ("cache_exchange", "move_rows", "scatter"),
+    report_layers("serve", found_x, [("cache_exchange", "move_rows",
+                                      "scatter")])
+    report_layers("serve", found_f, [
         ("embedding_forward", "move_rows", "gather"),
         ("interaction", "dot_interaction", "einsum"),
         ("mlps", None, "dot")])
-    if {"move_rows", "dot_interaction"} - found:
-        raise SystemExit(f"serve path compiled without kernels: {found}")
+    if "move_rows" not in found_x:
+        raise SystemExit(f"cache exchange compiled without move_rows: "
+                         f"{found_x}")
+    if {"move_rows", "dot_interaction"} - found_f:
+        raise SystemExit(f"serve forward compiled without kernels: "
+                         f"{found_f}")
     log(f"serve peak_bytes_in_use={peak_bytes(dev)} "
         f"hit_rate={engine.cache_stats.hit_rate:.4f}")
 
@@ -323,31 +369,39 @@ def tablewise_phase(cfg_full, cfg_small) -> None:
     del params, state
     gc.collect()
     dev0 = jax.devices()[0]
-    p1 = jax.device_put(init_params(dlrm_param_specs(cfg_small, ebc),
-                                    jax.random.PRNGKey(SEED)), dev0)
-    s1 = dlrm_init_state(ebc, opt, p1)
     step1 = jax.jit(build_dlrm_train_step(cfg_small, ebc, opt,
                                           sparse_apply="sparse"),
                     donate_argnums=(0, 1))
-    losses_1 = []
-    for s, raw in enumerate(data):
-        b = {k: jnp.asarray(v) for k, v in raw.items()}
-        p1, s1, m = step1(p1, s1, b, jnp.asarray(s, jnp.int32))
-        losses_1.append(float(m["loss"]))
-    want = [np.asarray(a) for a in cache_ops.cache_fetch(
-        p1["emb"]["mega"], s1["accum"], rows)]
-    del p1, s1
-    gc.collect()
-    for k, (a, b) in enumerate(zip(losses_t, losses_1)):
-        log(f"tablewise step {k}: loss mesh={a!r} single-device={b!r}")
+
+    def single_device(nudge: bool):
+        p1 = jax.device_put(init_params(dlrm_param_specs(cfg_small, ebc),
+                                        jax.random.PRNGKey(SEED)), dev0)
+        if nudge:
+            p1 = nudge_bottom(p1)
+        s1 = dlrm_init_state(ebc, opt, p1)
+        losses_1 = []
+        for s, raw in enumerate(data):
+            b = {k: jnp.asarray(v) for k, v in raw.items()}
+            p1, s1, m = step1(p1, s1, b, jnp.asarray(s, jnp.int32))
+            losses_1.append(float(m["loss"]))
+        want = [np.asarray(a) for a in cache_ops.cache_fetch(
+            p1["emb"]["mega"], s1["accum"], rows)]
+        del p1, s1
+        gc.collect()
+        return np.asarray(losses_1), want
+
+    losses_1, want = single_device(False)
+    losses_n, nudged = single_device(True)
+    for k, (a, b, c) in enumerate(zip(losses_t, losses_1, losses_n)):
+        log(f"tablewise step {k}: loss mesh={a!r} single-device={b!r} "
+            f"rel_diff={abs(a - b) / abs(b):.3e} (one-ulp nudge "
+            f"{abs(c - b) / abs(b):.3e})")
     exact = (np.array_equal(losses_t, losses_1)
              and all(np.array_equal(g, w) for g, w in zip(got, want)))
-    log(f"tablewise touched_rows={rows.shape[0]} bit_exact={exact} "
-        f"max_abs_diff rows={np.abs(got[0] - want[0]).max():.3e} "
-        f"accum={np.abs(got[1] - want[1]).max():.3e}")
+    log(f"tablewise touched_rows={rows.shape[0]} bit_exact={exact}")
     np.testing.assert_allclose(losses_t, losses_1, rtol=LOSS_RTOL)
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(g, w, rtol=ROW_RTOL, atol=ROW_ATOL)
+    check_rows("tablewise rows", got[0], want[0], nudged[0])
+    check_rows("tablewise accum", got[1], want[1], nudged[1])
 
     # 2) m2's full published heights over the four owners
     ebc = EmbeddingBagCollection.build(cfg_full, n_shards=4,

@@ -27,7 +27,7 @@ import numpy as np
 from repro.configs.base import DLRMConfig
 from repro.core.placement import PlacementPlan, plan_placement
 from repro.kernels import ops
-from repro.kernels.cache_ops import gather_rows
+from repro.kernels.row_move import gather_rows
 from repro.kernels.sparse_plan import build_sparse_plan
 from repro.nn.params import ParamSpec
 
@@ -39,7 +39,7 @@ def _row_taker(mega: jax.Array, idx: jax.Array, plan, use_kernel):
     is read once per unique row into a compact slab that every slot then
     reads through an index-only searchsorted remap. On TPU a plan is
     always built and the compact slab is read by the row-move kernel
-    (kernels/cache_ops.gather_rows): an XLA gather from the table would
+    (kernels/row_move.gather_rows): an XLA gather from the table would
     first relayout all of it into a padded copy twice its size."""
     kernel = ops.use_pallas(use_kernel)
     if plan is None and kernel:
@@ -47,7 +47,7 @@ def _row_taker(mega: jax.Array, idx: jax.Array, plan, use_kernel):
     if plan is None:
         return lambda flat: jnp.take(mega, flat, axis=0)
     if kernel:
-        compact = gather_rows(mega, plan.unique_rows, use_kernel=True)
+        compact = gather_rows(mega, plan.unique_rows)
     else:
         compact = jnp.take(mega, jnp.maximum(plan.unique_rows, 0), axis=0)
     sent = jnp.where(plan.unique_rows >= 0, plan.unique_rows,

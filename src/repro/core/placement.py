@@ -41,6 +41,8 @@ from collections.abc import Sequence
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.row_move import LANE
+
 
 #: device-side HBM overhead per CACHED row beyond the row payload:
 #: row-wise AdaGrad accumulator (fp32) + LFU frequency score (fp32)
@@ -48,9 +50,9 @@ CACHED_ROW_META_BYTES = 8
 
 #: every mega-table, per-shard and device-cache height is a multiple of
 #: this: the TPU kernels move rows as lanes of 128-row tiles of the table's
-#: (d, rows) view (kernels/sparse_update.py), which tiles such heights
-#: exactly and in place
-ROW_TILE = 128
+#: (d, rows) view (kernels/row_move.py), which tiles such heights exactly
+#: and in place
+ROW_TILE = LANE
 
 
 @dataclasses.dataclass(frozen=True)

@@ -12,8 +12,9 @@ kernels here cover exactly that path:
   sparse_update    row-wise AdaGrad applied in place to a batch's unique
                    rows (gradients aggregated by ref.bag_grad_sums over
                    the sparse_plan.py CSR bucketing) — the EMB update
-  cache_ops        one row-move kernel behind the cached tier's exchange,
-                   fetch and commit, and the TPU embedding gather
+  row_move         one in-place row-copy kernel on the tables' (d, rows)
+                   view: the TPU embedding gather, and every pass of
+  cache_ops        the cached tier's exchange, fetch and commit
   flash_attention  causal streaming attention with static triangle
                    skipping — the prefill_32k hot spot of the LM family
 

@@ -12,20 +12,13 @@ kernel then walks the unique rows in ascending order and, per row,
 writing the table row in place (io aliasing) and emitting acc' for the
 caller to scatter back.
 
-Layout ("rows on lanes"). Production tables are d=64 wide. TPU tiles are
-(8, 128), and XLA stores an (H, 64) fp32 array with the row axis minor, so
-a row-major (1, 64) row slice cannot be DMA'd at all, and padding the table
-to 128 lanes would copy it on every call. The kernel instead works on the
-free transposed view `table.T` of shape (d, H): row r is lane r % 128 of
-the 128-row tile r // 128. A touched tile is DMA'd into VMEM once, every
-unique row inside it is updated under a lane mask, and the tile is written
-back when the walk leaves it. Rows arrive sorted, so each touched tile
-moves once per grid step. Per-row values (gsum, acc) come in lane-dense
-blocks, and a `pltpu.roll` lines row j's column up with lane r % 128.
-
-SMEM holds only one block of `ROW_BLOCK` row ids per grid step, never the
-whole plan: the plan's capacity at a production batch (B*F*L = 1.7M) is far
-larger than SMEM.
+Layout: the "rows on lanes" view of row_move.py, (d, H) = `table.T`. A
+touched (d, 128) tile is DMA'd into VMEM once, every unique row inside it
+is updated under a lane mask, and the tile is written back when the walk
+leaves it; rows arrive sorted, so each touched tile moves once per grid
+step. Per-row values (gsum, acc) come in lane-dense blocks, and a
+`pltpu.roll` lines row j's column up with lane r % 128. Row ids stream
+through SMEM one `ROW_BLOCK` block per grid step.
 """
 from __future__ import annotations
 
@@ -37,19 +30,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.tpu import MemorySpace, SemaphoreType
 
-LANE = 128
-#: unique rows per grid step; a 1-D int32 SMEM block must be 1024 long to
-#: match XLA's T(1024) layout for the row-id array
-ROW_BLOCK = 1024
+from repro.kernels.row_move import LANE, ROW_BLOCK, pad_axis
 
-
-def _pad_axis(x: jax.Array, mult: int, axis: int, value=0) -> jax.Array:
-    pad = (-x.shape[axis]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
 
 
 def _apply_kernel(rows_ref, lr_ref, g_ref, acc_ref, table_in, table_out,
@@ -171,12 +153,12 @@ def rowwise_adagrad_apply(table: jax.Array, accum: jax.Array,
     valid = rows >= 0
     safe = jnp.where(valid, rows, 0)
     acc_u = jnp.where(valid, accum[safe], 0.0)
-    rows_p = _pad_axis(rows.astype(jnp.int32), ROW_BLOCK, 0, value=-1)
+    rows_p = pad_axis(rows.astype(jnp.int32), ROW_BLOCK, 0, value=-1)
     u = rows_p.shape[0]
-    g = _pad_axis(gsum.astype(jnp.float32), ROW_BLOCK, 0)
+    g = pad_axis(gsum.astype(jnp.float32), ROW_BLOCK, 0)
     gsum_t = g.reshape(u // LANE, LANE, d).transpose(0, 2, 1)
-    acc_p = _pad_axis(acc_u, ROW_BLOCK, 0).reshape(u // LANE, LANE)
-    table_t = _pad_axis(table.T, LANE, 1)
+    acc_p = pad_axis(acc_u, ROW_BLOCK, 0).reshape(u // LANE, LANE)
+    table_t = pad_axis(table.T, LANE, 1)
     new_t, acc_new = rowwise_adagrad_apply_kernel(
         table_t, rows_p, gsum_t, acc_p,
         jnp.asarray(lr, jnp.float32).reshape(1), eps=eps,

@@ -33,7 +33,10 @@ def linear_specs(d_in: int, d_out: int, in_ax: str, out_ax: str,
 def linear(p: dict[str, jax.Array], x: jax.Array,
            dtype=jnp.bfloat16) -> jax.Array:
     w = p["w"].astype(dtype)
-    y = jnp.einsum("...i,io->...o", x.astype(dtype), w)
+    # an fp32 matmul at default precision runs as bf16 passes on TPU
+    prec = (jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
+            else None)
+    y = jnp.einsum("...i,io->...o", x.astype(dtype), w, precision=prec)
     if "b" in p:
         y = y + p["b"].astype(dtype)
     return y

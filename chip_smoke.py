@@ -354,6 +354,8 @@ def tablewise_phase(cfg_full, cfg_small) -> None:
         log(f"tablewise[{label}] peak_bytes_in_use per device={peaks}")
         return params, state, np.asarray(losses)
 
+    log("tablewise layer interaction: XLA einsum (the dense tail is "
+        "partitioned by XLA on the mesh)")
     # 1) one-chip height: the table-wise step against the single-device step
     ebc = EmbeddingBagCollection.build(cfg_small, n_shards=4,
                                        strategy="table_wise")

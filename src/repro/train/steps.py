@@ -480,8 +480,11 @@ def _build_multihost_cached_step(cfg: DLRMConfig, mc,
              for h in range(hn)], axis=0)
 
         def loss_fn(dp, pl_):
-            logits = dlrm_forward_dense({**dp, "emb": None}, dev["dense"],
-                                        pl_, cfg, interpret)
+            # on a mesh the dense tail is partitioned by XLA, which cannot
+            # partition a Pallas call: the interaction takes the XLA op
+            logits = dlrm_forward_dense(
+                {**dp, "emb": None}, dev["dense"], pl_, cfg, interpret,
+                use_kernel=False if mesh is not None else None)
             return _bce(logits, dev["label"])
 
         loss, (g_dense, g_pooled) = jax.value_and_grad(
@@ -762,8 +765,11 @@ def build_tablewise_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
         owner-routed fused sparse update."""
 
         def loss_fn(dp, pl_):
-            logits = dlrm_forward_dense({**dp, "emb": None}, dev["dense"],
-                                        pl_, cfg, interpret)
+            # on a mesh the dense tail is partitioned by XLA, which cannot
+            # partition a Pallas call: the interaction takes the XLA op
+            logits = dlrm_forward_dense(
+                {**dp, "emb": None}, dev["dense"], pl_, cfg, interpret,
+                use_kernel=False if mesh is not None else None)
             return _bce(logits, dev["label"])
 
         loss, (g_dense, g_pooled) = jax.value_and_grad(

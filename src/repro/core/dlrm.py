@@ -22,6 +22,7 @@ from repro.configs.base import DLRMConfig
 from repro.core.embedding import EmbeddingBagCollection
 from repro.core.interaction import interact, interaction_dim
 from repro.nn.layers import linear, linear_specs
+from repro.tracing import scope
 
 # ---------------------------------------------------------------------------
 # params
@@ -79,10 +80,13 @@ def dlrm_forward_dense(params: dict, dense_x: jax.Array, pooled: jax.Array,
     `use_kernel=False` takes the jnp reference interaction on any backend.
     """
     dtype = jnp.float32 if cfg.compute_dtype == "float32" else jnp.bfloat16
-    bot = _mlp_apply(params["bottom"], dense_x.astype(dtype), dtype)
-    top_in = interact(bot, pooled.astype(dtype), cfg.interaction,
-                      use_kernel=use_kernel, interpret=interpret)
-    logit = _mlp_apply(params["top"], top_in, dtype)
+    with scope("bottom_mlp"):
+        bot = _mlp_apply(params["bottom"], dense_x.astype(dtype), dtype)
+    with scope("interaction"):
+        top_in = interact(bot, pooled.astype(dtype), cfg.interaction,
+                          use_kernel=use_kernel, interpret=interpret)
+    with scope("top_mlp"):
+        logit = _mlp_apply(params["top"], top_in, dtype)
     return logit[..., 0].astype(jnp.float32)
 
 
@@ -119,9 +123,10 @@ def dlrm_loss(params: dict, batch: dict, cfg: DLRMConfig,
 
 
 def _bce(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    return jnp.mean(
-        jnp.maximum(logits, 0) - logits * labels
-        + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+    with scope("loss"):
+        return jnp.mean(
+            jnp.maximum(logits, 0) - logits * labels
+            + jnp.log1p(jnp.exp(-jnp.abs(logits))))
 
 
 def normalized_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:

@@ -30,6 +30,7 @@ from repro.kernels import ops
 from repro.kernels.row_move import gather_rows
 from repro.kernels.sparse_plan import build_sparse_plan
 from repro.nn.params import ParamSpec
+from repro.tracing import scope
 
 
 def _row_taker(mega: jax.Array, idx: jax.Array, plan, use_kernel):
@@ -43,17 +44,25 @@ def _row_taker(mega: jax.Array, idx: jax.Array, plan, use_kernel):
     first relayout all of it into a padded copy twice its size."""
     kernel = ops.use_pallas(use_kernel)
     if plan is None and kernel:
-        plan = build_sparse_plan(idx)
+        with scope("sparse_plan"):
+            plan = build_sparse_plan(idx)
     if plan is None:
         return lambda flat: jnp.take(mega, flat, axis=0)
-    if kernel:
-        compact = gather_rows(mega, plan.unique_rows)
-    else:
-        compact = jnp.take(mega, jnp.maximum(plan.unique_rows, 0), axis=0)
-    sent = jnp.where(plan.unique_rows >= 0, plan.unique_rows,
-                     jnp.iinfo(jnp.int32).max)
-    return lambda flat: jnp.take(compact, jnp.searchsorted(sent, flat),
-                                 axis=0)
+    with scope("embedding_gather"):
+        if kernel:
+            compact = gather_rows(mega, plan.unique_rows)
+        else:
+            compact = jnp.take(mega, jnp.maximum(plan.unique_rows, 0),
+                               axis=0)
+        sent = jnp.where(plan.unique_rows >= 0, plan.unique_rows,
+                         jnp.iinfo(jnp.int32).max)
+
+    def take(flat):
+        with scope("embedding_remap"):
+            pos = jnp.searchsorted(sent, flat)
+        return jnp.take(compact, pos, axis=0)
+
+    return take
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,18 +155,20 @@ class EmbeddingBagCollection:
             rows = jnp.where(valid[..., None], rows.astype(jnp.float32), 0.0)
             return None, rows.sum(axis=1).astype(mega.dtype)
 
-        if f > 8:
-            # scan over features: bounds the (b, lk, d) gather transient to
-            # one feature at a time (m3 has 127 tables x 32 lookups)
-            _, pooled = jax.lax.scan(pool_one, None,
-                                     jnp.swapaxes(idx, 0, 1))
-            pooled = jnp.swapaxes(pooled, 0, 1)              # (b, f, d)
-        else:
-            valid = idx >= 0
-            rows = take(jnp.maximum(idx, 0).reshape(-1))
-            rows = rows.reshape(b, f, lk, -1)
-            rows = jnp.where(valid[..., None], rows.astype(jnp.float32), 0.0)
-            pooled = rows.sum(axis=2).astype(mega.dtype)
+        with scope("embedding_pool"):
+            if f > 8:
+                # scan over features: bounds the (b, lk, d) gather transient
+                # to one feature at a time (m3 has 127 tables x 32 lookups)
+                _, pooled = jax.lax.scan(pool_one, None,
+                                         jnp.swapaxes(idx, 0, 1))
+                pooled = jnp.swapaxes(pooled, 0, 1)          # (b, f, d)
+            else:
+                valid = idx >= 0
+                rows = take(jnp.maximum(idx, 0).reshape(-1))
+                rows = rows.reshape(b, f, lk, -1)
+                rows = jnp.where(valid[..., None],
+                                 rows.astype(jnp.float32), 0.0)
+                pooled = rows.sum(axis=2).astype(mega.dtype)
         return shard_activation(pooled, ("act_batch", None, None),
                                 rules or {})
 
@@ -187,11 +198,14 @@ class EmbeddingBagCollection:
             b, f, lk = loc.shape
             valid = loc >= 0
             take = _row_taker(mega_shard, loc, None, use_kernel)
-            rows = take(jnp.maximum(loc, 0).reshape(-1)).reshape(b, f, lk, d)
-            rows = jnp.where(valid[..., None], rows.astype(jnp.float32),
-                             0.0)
-            pooled = rows.sum(axis=2)          # POOL BEFORE the collective
-            return jax.lax.psum(pooled, model_axis)
+            with scope("embedding_pool"):
+                rows = take(jnp.maximum(loc, 0).reshape(-1)).reshape(
+                    b, f, lk, d)
+                rows = jnp.where(valid[..., None], rows.astype(jnp.float32),
+                                 0.0)
+                pooled = rows.sum(axis=2)      # POOL BEFORE the collective
+            with scope("embedding_exchange"):
+                return jax.lax.psum(pooled, model_axis)
 
         return jax.shard_map(
             local_fn, mesh=mesh,

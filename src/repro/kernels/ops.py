@@ -24,6 +24,7 @@ from repro.kernels.embedding_bag import (dedup_embedding_bag_kernel,
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.sparse_plan import SparsePlan, build_sparse_plan
 from repro.kernels.sparse_update import rowwise_adagrad_apply
+from repro.tracing import scope
 
 LANE = 128
 SUBLANE = 8
@@ -236,13 +237,16 @@ def fused_sparse_backward(table: jax.Array, accum: jax.Array,
     d = table.shape[1]
     if plan is None:
         assert idx is not None, "need idx to build a SparsePlan"
-        plan = build_sparse_plan(idx)
+        with scope("sparse_plan"):
+            plan = build_sparse_plan(idx)
     pooled2 = pooled_grad.reshape(-1, d)
     if use_pallas(use_kernel) or interpret:
-        gsum = ref.bag_grad_sums(plan.unique_rows, plan.bag_offsets,
-                                 plan.bag_ids, pooled2)
-        return rowwise_adagrad_apply(table, accum, plan.unique_rows, gsum,
-                                     lr, eps, interpret)
+        with scope("bag_grad_sums"):
+            gsum = ref.bag_grad_sums(plan.unique_rows, plan.bag_offsets,
+                                     plan.bag_ids, pooled2)
+        with scope("rowwise_adagrad"):
+            return rowwise_adagrad_apply(table, accum, plan.unique_rows,
+                                         gsum, lr, eps, interpret)
     return ref.fused_bag_backward_adagrad_ref(
         table, accum, plan.unique_rows, plan.bag_offsets, plan.bag_ids,
         pooled2, lr, eps)
@@ -284,9 +288,11 @@ def fused_sparse_backward_segments(table: jax.Array, accum: jax.Array,
     offs_flat = jnp.concatenate(
         [seg_offsets[:, :-1].reshape(-1), seg_offsets[-1:, -1]])
     if use_pallas(use_kernel) or interpret:
-        gsum = ref.bag_grad_sums_abs(offs_flat, bag_ids, pooled2)
-        return rowwise_adagrad_apply(table, accum, rows_flat, gsum, lr, eps,
-                                     interpret)
+        with scope("bag_grad_sums"):
+            gsum = ref.bag_grad_sums_abs(offs_flat, bag_ids, pooled2)
+        with scope("rowwise_adagrad"):
+            return rowwise_adagrad_apply(table, accum, rows_flat, gsum, lr,
+                                         eps, interpret)
     return ref.fused_bag_backward_adagrad_abs_ref(
         table, accum, rows_flat, offs_flat, bag_ids, pooled2, lr, eps)
 

@@ -9,6 +9,12 @@ dlrm-m2's published widths.
 Features exercised: sharded params, data pipeline with host prefetch,
 AdamW/AdaGrad split, checkpoint/restore (resumable), preemption handling,
 straggler logging, EASGD / local-SGD pod sync (optional).
+
+At exit a run prints, beside the stragglers flagged, the loop's counters
+(`repro.tracing.LoopCounters.summary`): seconds in each phase of the loop
+(next batch, host-to-device copy, dispatch, loss read, checkpoint), the
+slowest step and how its time split, and the steps in which anything was
+compiled. Under `jax.profiler` the same phases are host spans of the trace.
 """
 from __future__ import annotations
 
@@ -29,6 +35,7 @@ from repro.models.lm import lm_param_specs
 from repro.nn.params import init_params
 from repro.nn.sharding import TRAIN_RULES
 from repro.optim.optimizers import adagrad, adamw
+from repro.tracing import LoopCounters
 from repro.train.checkpoint import CheckpointManager
 from repro.train.fault_tolerance import (FaultInjector, PreemptionHandler,
                                          StragglerDetector, TrainState,
@@ -80,32 +87,50 @@ def dlrm_job(cfg: DLRMConfig, batch: int, key,
 
 def train_loop(step_fn, params, state, pipeline, n_steps: int, *,
                start: int = 0, log_every: int = 10, save=None,
-               ckpt_every: int = 0, preempt=None, straggler=None):
+               ckpt_every: int = 0, preempt=None, straggler=None,
+               counters: LoopCounters | None = None):
     """The launcher's training loop: pull (step, batch) from `pipeline`,
     run `step_fn`, checkpoint through `save(step, params, state)` every
     `ckpt_every` steps (none when `save` is None), stop early on
-    preemption. Returns (params, state, losses, last_step)."""
+    preemption. Returns (params, state, losses, last_step).
+
+    Each step is a profiler step `train` whose phases are host spans
+    (`repro.tracing.PHASES`): `train.next_batch`, `train.h2d` (the batch to
+    the device), `train.dispatch`, `train.loss_read` (which waits for the
+    device), and `train.checkpoint` around each save. `counters`, when
+    given, sums the phases, keeps the slowest step's split and counts the
+    compilations made while the loop runs."""
     live = {"params": params, "state": state}
     losses = []
+    counters = counters if counters is not None else LoopCounters()
 
     def one_step(step):
-        _, batch = next(pipeline)
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        live["params"], live["state"], metrics = step_fn(
-            live["params"], live["state"], batch,
-            jnp.asarray(step, jnp.int32))
-        loss = float(metrics["loss"])
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            counters.begin_step(step)
+            with counters.phase("next_batch"):
+                _, batch = next(pipeline)
+            with counters.phase("h2d"):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            with counters.phase("dispatch"):
+                live["params"], live["state"], metrics = step_fn(
+                    live["params"], live["state"], batch,
+                    jnp.asarray(step, jnp.int32))
+            with counters.phase("loss_read"):
+                loss = float(metrics["loss"])
         losses.append(loss)
         if step % log_every == 0:
             print(f"step {step:5d} loss {loss:.4f}")
 
     def checkpoint(step):
         if save is not None:
-            save(step, live["params"], live["state"])
+            with counters.phase("checkpoint"):
+                save(step, live["params"], live["state"])
 
-    last = run_resilient_loop(one_step, n_steps, checkpoint,
-                              ckpt_every or max(n_steps, 1), preempt,
-                              straggler, start_step=start)
+    with counters.watch_compiles():
+        last = run_resilient_loop(one_step, n_steps, checkpoint,
+                                  ckpt_every or max(n_steps, 1), preempt,
+                                  straggler, start_step=start,
+                                  on_step=counters.end_step)
     return live["params"], live["state"], losses, last
 
 
@@ -186,14 +211,16 @@ def main():
     def save(step, params, state):
         ckpt.save(step, {"params": params, "state": state}, async_=True)
 
+    counters = LoopCounters()
     _, _, losses, last = train_loop(
         step_fn, params, state, pipeline, args.steps, start=start,
         log_every=args.log_every, save=save, ckpt_every=args.ckpt_every,
-        preempt=preempt, straggler=straggler)
+        preempt=preempt, straggler=straggler, counters=counters)
     ckpt.wait()
     pipeline.close()
     print(f"done at step {last}; loss {losses[0]:.4f} -> {losses[-1]:.4f}; "
           f"stragglers flagged: {len(straggler.flagged_steps)}")
+    print(counters.summary())
 
 
 def _chaos_main(args, inj, ckpt, preempt, loader, specs, key,

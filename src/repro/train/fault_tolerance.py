@@ -423,14 +423,18 @@ def run_resilient_loop(step_fn: Callable, n_steps: int,
                        straggler: StragglerDetector | None = None,
                        on_straggler: Callable[[int], None] | None = None,
                        start_step: int = 0,
-                       injector: FaultInjector | None = None) -> int:
+                       injector: FaultInjector | None = None,
+                       on_step: Callable[[int, float], None] | None = None
+                       ) -> int:
     """Generic resilient loop driver; returns the last completed step.
 
     step_fn(step) performs one train step (device sync included). A
     preemption coinciding with a scheduled checkpoint saves ONCE (the
     scheduled save already covers the step). `injector` fires the
     "loop.step" site before each step; a "preempt" spec triggers the
-    preemption handler exactly as a SIGTERM would.
+    preemption handler exactly as a SIGTERM would. `on_step(step, seconds)`
+    gets each step's wall time: the lap since the previous step's, which
+    holds that step's checkpoint.
     """
     timer = StepTimer()
     step = start_step
@@ -441,6 +445,8 @@ def run_resilient_loop(step_fn: Callable, n_steps: int,
                 preemption.trigger()
         step_fn(step)
         dt = timer.lap()
+        if on_step is not None:
+            on_step(step, dt)
         if straggler is not None and straggler.record(dt) and on_straggler:
             on_straggler(step)
         step += 1

@@ -38,6 +38,7 @@ from repro.models.lm import lm_loss
 from repro.nn.sharding import (TRAIN_RULES, LogicalRules,
                                _live_mesh_axis_names)
 from repro.optim.optimizers import Optimizer
+from repro.tracing import scope
 
 
 def _constrain(x, pspec):
@@ -238,9 +239,10 @@ def build_dlrm_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
     def step(params, state, batch, step_idx):
         loss, g_dense, (idx, g_pooled) = dlrm_grads(
             params, batch, cfg, ebc, interpret, rules, use_kernel)
-        new_dense, new_dense_state = dense_opt.apply(
-            {"bottom": params["bottom"], "top": params["top"]},
-            g_dense, state["dense"], step_idx)
+        with scope("dense_optimizer"):
+            new_dense, new_dense_state = dense_opt.apply(
+                {"bottom": params["bottom"], "top": params["top"]},
+                g_dense, state["dense"], step_idx)
         if sparse_apply == "sparse":
             apply_fn = sparse_update_nrows
         elif cfg.lookup_impl == "psum":
@@ -774,8 +776,9 @@ def build_tablewise_train_step(cfg: DLRMConfig, ebc: EmbeddingBagCollection,
 
         loss, (g_dense, g_pooled) = jax.value_and_grad(
             loss_fn, argnums=(0, 1))(dense_params, pooled)
-        new_dense, new_dense_state = dense_opt.apply(
-            dense_params, g_dense, dense_state, step_idx)
+        with scope("dense_optimizer"):
+            new_dense, new_dense_state = dense_opt.apply(
+                dense_params, g_dense, dense_state, step_idx)
         pooled2 = g_pooled.astype(jnp.float32).reshape(-1, d)
         if mesh is not None:
             from jax.sharding import PartitionSpec as SP

@@ -28,41 +28,52 @@ from repro.configs.base import DLRMConfig
 from repro.core.placement import PlacementPlan, plan_placement
 from repro.kernels import ops
 from repro.kernels.row_move import gather_rows
-from repro.kernels.sparse_plan import build_sparse_plan
+from repro.kernels.sparse_plan import build_sparse_plan_with_slots
 from repro.nn.params import ParamSpec
 from repro.tracing import scope
 
 
 def _row_taker(mega: jax.Array, idx: jax.Array, plan, use_kernel):
-    """take(flat_rows) -> mega rows for the lookups of `idx` (B, F, L).
+    """(slots, take) for the lookups of `idx` (B, F, L): `slots` has idx's
+    shape, and take(flat_slots) -> the mega rows those slots look up.
 
-    Without a plan, a direct gather per lookup slot. With one, the table
-    is read once per unique row into a compact slab that every slot then
-    reads through an index-only searchsorted remap. On TPU a plan is
-    always built and the compact slab is read by the row-move kernel
+    Without a plan, the slots are idx's rows and take gathers them from
+    the table directly. With one, the table is read once per unique row
+    into a compact slab that the slots index. On TPU with no plan handed
+    in, one is built here from idx, and each slot's position in the slab
+    comes from the plan's own sort (`build_sparse_plan_with_slots`); a
+    plan handed in (a pipeline hook's, the cached steps' slot-relabelled
+    one) was sorted elsewhere, so take finds each slot's row in it by a
+    binary search over its sorted rows (`jnp.searchsorted`). On TPU the
+    compact slab is read by the row-move kernel
     (kernels/row_move.gather_rows): an XLA gather from the table would
     first relayout all of it into a padded copy twice its size."""
     kernel = ops.use_pallas(use_kernel)
+    slots = None
     if plan is None and kernel:
         with scope("sparse_plan"):
-            plan = build_sparse_plan(idx)
+            plan, slots = build_sparse_plan_with_slots(idx)
     if plan is None:
-        return lambda flat: jnp.take(mega, flat, axis=0)
+        return idx, lambda ids: jnp.take(mega, jnp.maximum(ids, 0), axis=0)
     with scope("embedding_gather"):
         if kernel:
             compact = gather_rows(mega, plan.unique_rows)
         else:
             compact = jnp.take(mega, jnp.maximum(plan.unique_rows, 0),
                                axis=0)
+    if slots is not None:
+        return slots, lambda pos: jnp.take(compact, pos, axis=0)
+    with scope("embedding_gather"):
         sent = jnp.where(plan.unique_rows >= 0, plan.unique_rows,
                          jnp.iinfo(jnp.int32).max)
 
-    def take(flat):
+    def take(ids):
+        ids = jnp.maximum(ids, 0)
         with scope("embedding_remap"):
-            pos = jnp.searchsorted(sent, flat)
+            pos = jnp.searchsorted(sent, ids)
         return jnp.take(compact, pos, axis=0)
 
-    return take
+    return idx, take
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,23 +145,25 @@ class EmbeddingBagCollection:
         rehydrates) DEDUPLICATES the mega-table gather: the table is
         touched once per plan entry (its unique capacity U, not B*F*L) into
         a compact hot buffer, and every lookup slot then reads that buffer
-        through an index-only searchsorted remap. The pooling that follows
-        is the SAME code either way, so the planned path is BIT-EXACT vs
-        the plan-less one (asserted in tests/test_dedup_forward.py) — the
-        forward half of the plan-once-used-thrice contract
-        (docs/embedding_forward.md). On TPU the table rows are read by the
-        row-move kernel (`use_kernel=False` keeps the XLA gather; see
-        `_row_taker`)."""
+        at its row's position, found by a binary search over the plan's
+        sorted rows. On TPU, with no plan given, the lookup builds one from
+        idx and reads each slot's position off that plan's own sort
+        instead: no search. The pooling that follows is the SAME code
+        either way, so the planned path is BIT-EXACT vs the plan-less one
+        (asserted in tests/test_dedup_forward.py) — the forward half of
+        the plan-once-used-thrice contract (docs/embedding_forward.md). On
+        TPU the table rows are read by the row-move kernel
+        (`use_kernel=False` keeps the XLA gather; see `_row_taker`)."""
         from repro.nn.sharding import shard_activation
         mega = params["mega"]
         b, f, lk = idx.shape
-        take = _row_taker(mega, idx, plan, use_kernel)
+        slots, take = _row_taker(mega, idx, plan, use_kernel)
 
-        def pool_one(_, idx_f):
+        def pool_one(_, xs):
             """Pool one feature's bags; scanned over the feature axis."""
-            # idx_f: (b, lk) one feature's bags
+            idx_f, slots_f = xs        # (b, lk) one feature's bags
             valid = idx_f >= 0
-            rows = take(jnp.maximum(idx_f, 0).reshape(-1))
+            rows = take(slots_f.reshape(-1))
             rows = rows.reshape(b, lk, -1)
             rows = jnp.where(valid[..., None], rows.astype(jnp.float32), 0.0)
             return None, rows.sum(axis=1).astype(mega.dtype)
@@ -159,13 +172,13 @@ class EmbeddingBagCollection:
             if f > 8:
                 # scan over features: bounds the (b, lk, d) gather transient
                 # to one feature at a time (m3 has 127 tables x 32 lookups)
-                _, pooled = jax.lax.scan(pool_one, None,
-                                         jnp.swapaxes(idx, 0, 1))
+                _, pooled = jax.lax.scan(
+                    pool_one, None,
+                    (jnp.swapaxes(idx, 0, 1), jnp.swapaxes(slots, 0, 1)))
                 pooled = jnp.swapaxes(pooled, 0, 1)          # (b, f, d)
             else:
                 valid = idx >= 0
-                rows = take(jnp.maximum(idx, 0).reshape(-1))
-                rows = rows.reshape(b, f, lk, -1)
+                rows = take(slots.reshape(-1)).reshape(b, f, lk, -1)
                 rows = jnp.where(valid[..., None],
                                  rows.astype(jnp.float32), 0.0)
                 pooled = rows.sum(axis=2).astype(mega.dtype)
@@ -197,10 +210,9 @@ class EmbeddingBagCollection:
                             idx_local - lo, -1)
             b, f, lk = loc.shape
             valid = loc >= 0
-            take = _row_taker(mega_shard, loc, None, use_kernel)
+            slots, take = _row_taker(mega_shard, loc, None, use_kernel)
             with scope("embedding_pool"):
-                rows = take(jnp.maximum(loc, 0).reshape(-1)).reshape(
-                    b, f, lk, d)
+                rows = take(slots.reshape(-1)).reshape(b, f, lk, d)
                 rows = jnp.where(valid[..., None], rows.astype(jnp.float32),
                                  0.0)
                 pooled = rows.sum(axis=2)      # POOL BEFORE the collective

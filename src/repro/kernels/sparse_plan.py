@@ -21,7 +21,9 @@ fused accumulation bit-identical to the legacy scatter-add.
 Two implementations with identical outputs:
   * `build_sparse_plan` — pure jnp, jits on-device (used inside train steps
     and shard_map bodies; lowering contains no float tensors — asserted in
-    tests/test_sparse_fused.py);
+    tests/test_sparse_fused.py); `build_sparse_plan_with_slots` adds each
+    lookup slot's position in `unique_rows`, from the same sort, for the
+    forward that builds its own plan (core/embedding.py);
   * `build_sparse_plan_host` — numpy, for the data-pipeline reader thread
     (`data.sparse_plan_hook`) so batch k+1's plan is built while batch k
     computes, mirroring the async cache-exchange overlap of PR 2.
@@ -33,6 +35,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.tracing import scope
 
 # rows are mega-table offsets (< total_rows << 2**31), so int32 max is a safe
 # sort-last sentinel for -1 padding slots
@@ -286,6 +290,52 @@ def coalesce_rows(rows: np.ndarray, chunk: int, total_rows: int,
     return np.asarray(starts_list, np.int32), pos
 
 
+class _LookupSort(NamedTuple):
+    """The sort of one lookup stream that every on-device builder reads."""
+    order: jax.Array      # (N,) flat slot at each sorted position
+    s: jax.Array          # (N,) sorted rows, pads as _SENTINEL last
+    head: jax.Array       # (N,) bool, first slot of each unique row's run
+    rank: jax.Array       # (N,) unique-row index of each sorted slot
+    n_valid: jax.Array    # () int32 non-pad slots
+    lk: int               # lookups per bag
+
+
+def _sort_lookups(idx: jax.Array,
+                  lookups_per_bag: int | None) -> _LookupSort:
+    if idx.ndim == 3:
+        _, _, lk = idx.shape
+    else:
+        assert lookups_per_bag is not None, "flat idx needs lookups_per_bag"
+        lk = lookups_per_bag
+    flat = idx.reshape(-1).astype(jnp.int32)
+    valid = flat >= 0
+    safe = jnp.where(valid, flat, _SENTINEL)          # pads sort last
+    order = jnp.argsort(safe)                         # stable: flat order
+    s = safe[order]                                   # kept within a run
+    head = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]]) \
+        & (s != _SENTINEL)
+    rank = jnp.cumsum(head) - 1                       # unique id at heads
+    return _LookupSort(order, s, head, rank,
+                       valid.sum().astype(jnp.int32), lk)
+
+
+def _plan_from_sort(srt: _LookupSort, capacity: int | None) -> SparsePlan:
+    n = srt.s.shape[0]
+    bag_ids = (srt.order // srt.lk).astype(jnp.int32)
+    unique_rows = jnp.full((n,), -1, jnp.int32).at[
+        jnp.where(srt.head, srt.rank, n)].set(srt.s, mode="drop")
+    # run i starts at its head's sorted position; runs are contiguous and
+    # valid slots sort first, so offsets[i+1] doubles as run i's end and the
+    # n_valid fill closes the last run / empties the tail
+    bag_offsets = jnp.full((n + 1,), srt.n_valid, jnp.int32).at[
+        jnp.where(srt.head, srt.rank, n + 1)].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+    if capacity is not None and capacity < n:
+        unique_rows = unique_rows[:capacity]
+        bag_offsets = bag_offsets[:capacity + 1]
+    return SparsePlan(unique_rows, bag_offsets, bag_ids)
+
+
 def build_sparse_plan(idx: jax.Array,
                       lookups_per_bag: int | None = None,
                       capacity: int | None = None) -> SparsePlan:
@@ -299,34 +349,28 @@ def build_sparse_plan(idx: jax.Array,
     The trim is a static slice, so the CALLER owns the contract that the
     batch's unique count fits (jit cannot raise data-dependently; the host
     twin below DOES raise, which is what the reader-thread hook runs)."""
-    if idx.ndim == 3:
-        _, _, lk = idx.shape
-    else:
-        assert lookups_per_bag is not None, "flat idx needs lookups_per_bag"
-        lk = lookups_per_bag
-    flat = idx.reshape(-1).astype(jnp.int32)
-    n = flat.shape[0]
-    valid = flat >= 0
-    safe = jnp.where(valid, flat, _SENTINEL)          # pads sort last
-    order = jnp.argsort(safe)                         # stable: flat order
-    s = safe[order]                                   # kept within a run
-    bag_ids = (order // lk).astype(jnp.int32)
-    head = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]]) \
-        & (s != _SENTINEL)
-    rank = jnp.cumsum(head) - 1                       # unique id at heads
-    n_valid = valid.sum().astype(jnp.int32)
-    unique_rows = jnp.full((n,), -1, jnp.int32).at[
-        jnp.where(head, rank, n)].set(s, mode="drop")
-    # run i starts at its head's sorted position; runs are contiguous and
-    # valid slots sort first, so offsets[i+1] doubles as run i's end and the
-    # n_valid fill closes the last run / empties the tail
-    bag_offsets = jnp.full((n + 1,), n_valid, jnp.int32).at[
-        jnp.where(head, rank, n + 1)].set(
-            jnp.arange(n, dtype=jnp.int32), mode="drop")
-    if capacity is not None and capacity < n:
-        unique_rows = unique_rows[:capacity]
-        bag_offsets = bag_offsets[:capacity + 1]
-    return SparsePlan(unique_rows, bag_offsets, bag_ids)
+    return _plan_from_sort(_sort_lookups(idx, lookups_per_bag), capacity)
+
+
+def build_sparse_plan_with_slots(idx: jax.Array
+                                 ) -> tuple[SparsePlan, jax.Array]:
+    """`build_sparse_plan` of idx (B, F, L) plus each lookup slot's
+    position in the plan's `unique_rows`, (B, F, L): what
+    `searchsorted(unique_rows, idx)` gives on every valid slot, read off
+    the plan's own sort instead of searched for. A sorted slot's position
+    is its run's `rank`; sorting (order, rank) by order carries it back
+    to flat slot order. Pad slots read the last live position (0 when
+    there is none), so every value indexes a row of the plan. The sort of
+    the ids is the same ops as `build_sparse_plan`'s, so a step that
+    builds both merges them into one; the one sort added here is the
+    `embedding_remap` layer's."""
+    srt = _sort_lookups(idx, None)
+    plan = _plan_from_sort(srt, None)
+    with scope("embedding_remap"):
+        # order is a permutation: no ties, so no stable sort's extra key
+        _, slots = jax.lax.sort((srt.order, jnp.maximum(srt.rank, 0)),
+                                num_keys=1, is_stable=False)
+    return plan, slots.astype(jnp.int32).reshape(idx.shape)
 
 
 def build_sparse_plan_host(idx: np.ndarray,

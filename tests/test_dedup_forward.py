@@ -4,6 +4,7 @@ corpus, interpret-mode sweep of the new Pallas kernel, plan capacity
 trimming, the index-only StableHLO gather check, the cached tiers' miss
 planning through the plan, and the forward-traffic acceptance model."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -11,14 +12,17 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.core import embedding
 from repro.core.cache import CachedEmbeddingBagCollection
 from repro.core.dlrm import dlrm_param_specs
 from repro.core.embedding import EmbeddingBagCollection
 from repro.data.pipeline import sparse_plan_hook
 from repro.data.synthetic import make_dlrm_batch
 from repro.kernels import ops, ref
+from repro.kernels.row_move import gather_rows
 from repro.kernels.sparse_plan import (SparsePlan, build_sparse_plan,
-                                       build_sparse_plan_host)
+                                       build_sparse_plan_host,
+                                       build_sparse_plan_with_slots)
 from repro.launch.analysis import (embedding_forward_traffic,
                                    zipf_expected_unique)
 from repro.nn.params import init_params
@@ -257,6 +261,117 @@ def test_lookup_with_plan_bit_exact_scan_path(rng):
                               hash_sizes=(40,) * f,
                               mean_lookups=(3,) * f)
     _planned_vs_plain_lookup(cfg, rng)
+
+
+# ---------------------------------------------------------------------------
+# slot positions read off the plan's own sort (the built-plan forward)
+# ---------------------------------------------------------------------------
+
+
+def _slot_cases(rng):
+    """name -> (idx (B, F, L), capacity or None)."""
+    dup = rng.randint(0, 30, size=(4, 3, 6)).astype(np.int32)
+    dup[:, :, 1::2] = dup[:, :, ::2]                # each row twice per bag
+    dup[0, 0, 3:] = -1
+    pad_feature = rng.randint(-1, 30, size=(5, 4, 5)).astype(np.int32)
+    pad_feature[:, 2] = -1                          # one feature all pads
+    heights = np.array([5, 7, 3])                   # tables' rows
+    offs = np.concatenate([[0], np.cumsum(heights)[:-1]])
+    edge = np.stack([offs, offs + heights - 1])     # first, last row each
+    bounds = edge[rng.randint(0, 2, size=(6, 3, 4)),
+                  np.arange(3)[None, :, None]].astype(np.int32)
+    bounds[1, :, 2:] = -1
+    trimmed = _zipf_idx2(rng, 12, 8, 40).reshape(6, 2, 8)
+    n_unique = len(np.unique(trimmed[trimmed >= 0]))
+    scan = rng.randint(-1, 50, size=(4, 10, 3)).astype(np.int32)   # f > 8
+    return {"dup_in_bag": (dup, None), "all_pad_feature": (pad_feature, None),
+            "table_bounds": (bounds, None),
+            "capacity_trimmed": (trimmed, n_unique + 2),
+            "scan_shape": (scan, None)}
+
+
+@pytest.mark.parametrize("case", ["dup_in_bag", "all_pad_feature",
+                                  "table_bounds", "capacity_trimmed",
+                                  "scan_shape"])
+def test_plan_slots_equal_searchsorted(rng, case):
+    """Each valid slot's position from the plan's sort is where a binary
+    search over the plan's sorted rows finds the slot's row, in the
+    full plan and in one trimmed to a capacity (every live position lies
+    below the unique count); every position, pads' too, indexes a row of
+    the plan; and the plan is `build_sparse_plan`'s."""
+    idx, cap = _slot_cases(rng)[case]
+    plan, slots = jax.jit(build_sparse_plan_with_slots)(jnp.asarray(idx))
+    for a, b in zip(plan, build_sparse_plan(jnp.asarray(idx))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    rows = np.asarray(
+        build_sparse_plan(jnp.asarray(idx), capacity=cap).unique_rows)
+    sent = np.where(rows >= 0, rows, np.iinfo(np.int32).max)
+    want = np.searchsorted(sent, np.maximum(idx, 0))
+    slots = np.asarray(slots)
+    assert slots.shape == idx.shape and slots.dtype == np.int32
+    valid = idx >= 0
+    np.testing.assert_array_equal(slots[valid], want[valid])
+    np.testing.assert_array_equal(rows[slots[valid]], idx[valid])
+    assert slots.min() >= 0 and slots.max() < rows.shape[0]
+
+
+def _scan_ebc(rng, strategy="replicated"):
+    cfg = get_smoke_config("dlrm-m1")
+    f = 10
+    cfg = dataclasses.replace(cfg, n_sparse_features=f,
+                              hash_sizes=(40,) * f, mean_lookups=(3,) * f)
+    return _lookup_case(cfg, rng, strategy)
+
+
+def _lookup_case(cfg, rng, strategy="replicated"):
+    ebc = EmbeddingBagCollection.build(cfg, n_shards=1, strategy=strategy)
+    raw = make_dlrm_batch(cfg, 8)
+    idx = ebc.offset_indices(jnp.asarray(raw["idx"]))
+    mega = jnp.asarray(rng.randn(ebc.plan.total_rows,
+                                 cfg.embed_dim).astype(np.float32))
+    return ebc, {"mega": mega}, idx
+
+
+def _lookup_fn(ebc, path, **kw):
+    """The lookup a step runs on `path`, as fn(params, idx)."""
+    if path == "psum":
+        mesh = jax.make_mesh((1,), ("model",))
+        return lambda p, i: ebc.lookup_pooled_psum(p, i, mesh, **kw)
+    return lambda p, i: ebc.lookup(p, i, **kw)
+
+
+@pytest.mark.parametrize("path", ["built", "supplied", "psum"])
+def test_only_a_supplied_plan_searches(rng, path):
+    """The TPU lookup traced on the CPU: a plan the lookup builds from idx
+    (the uncached step's, the table-wise shard's) gives each slot's
+    position from its own sort, so no `searchsorted` is traced; a plan
+    handed in keeps the search."""
+    ebc, params, idx = _scan_ebc(
+        rng, "table_wise" if path == "psum" else "replicated")
+    kw = {"use_kernel": True}
+    if path == "supplied":
+        kw["plan"] = build_sparse_plan(idx)
+    jaxpr = str(jax.make_jaxpr(_lookup_fn(ebc, path, **kw))(params, idx))
+    assert "gather_rows" in jaxpr or "move_rows" in jaxpr
+    assert ("searchsorted" in jaxpr) == (path == "supplied")
+
+
+@pytest.mark.parametrize("path", ["direct", "scan", "psum"])
+def test_built_plan_lookup_bit_exact_vs_plain(rng, monkeypatch, path):
+    """The TPU lookup (plan built from idx, slots from its sort, compact
+    slab read by the row-move kernel, here interpreted) pools the same
+    bits as the plan-less lookup, on the f <= 8 direct branch, the f > 8
+    scan and the table-wise shard."""
+    monkeypatch.setattr(embedding, "gather_rows",
+                        functools.partial(gather_rows, interpret=True))
+    if path == "direct":
+        ebc, params, idx = _lookup_case(get_smoke_config("dlrm-m1"), rng)
+    else:
+        ebc, params, idx = _scan_ebc(
+            rng, "table_wise" if path == "psum" else "replicated")
+    plain = jax.jit(_lookup_fn(ebc, path, use_kernel=False))(params, idx)
+    built = jax.jit(_lookup_fn(ebc, path, use_kernel=True))(params, idx)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(built))
 
 
 def test_lookup_local_dedup_matches_legacy(rng):

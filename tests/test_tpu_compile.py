@@ -8,6 +8,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,10 +121,11 @@ def test_dot_interaction_kernel_compiles_at_train_shapes(one_chip, m2):
            .lower(z).compile(), "dot_interaction")
 
 
-def test_m2_train_step_compiles_for_one_chip(one_chip, m2):
+@pytest.fixture(scope="module")
+def m2_step(one_chip, m2):
     """The launcher's one-chip step (unique-row apply, params and state
-    donated) at the chip smoke's size, every kernel branch taken: it fits
-    16 GB and updates the table in place."""
+    donated) at the chip smoke's size, every kernel branch taken,
+    compiled."""
     cfg, ebc, _ = m2
     opt = adagrad(0.01)
     params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
@@ -136,10 +138,27 @@ def test_m2_train_step_compiles_for_one_chip(one_chip, m2):
              "idx": _spec(one_chip, (b, cfg.n_sparse_features,
                                      cfg.truncation), jnp.int32),
              "label": _spec(one_chip, (b,))}
-    compiled = dlrm_train_step(cfg, ebc, opt, use_kernel=True).lower(
+    return dlrm_train_step(cfg, ebc, opt, use_kernel=True).lower(
         params, state, batch, _spec(one_chip, (), jnp.int32)).compile()
-    mem = _check(compiled, None)
-    assert set(chip_smoke.KERNELS) <= chip_smoke.kernels_in(compiled)
+
+
+def test_m2_train_step_compiles_for_one_chip(m2, m2_step):
+    """The step fits 16 GB and updates the table in place."""
+    cfg, ebc, _ = m2
+    mem = _check(m2_step, None)
+    assert set(chip_smoke.KERNELS) <= chip_smoke.kernels_in(m2_step)
     table = ebc.plan.total_rows * cfg.embed_dim * 4
     assert mem.alias_size_in_bytes >= table
     assert mem.temp_size_in_bytes < table
+
+
+def test_m2_train_step_sorts_the_ids_once(m2_step):
+    """The forward and the backward each build the plan from the batch's
+    ids, and XLA merges the two into one argsort; each lookup slot's
+    position in the plan comes from one more sort (`embedding_remap`),
+    not from a binary search per slot."""
+    text = m2_step.as_text()
+    sorts = re.findall(r' sort\(.*op_name="([^"]*)"', text)
+    assert sum(n.endswith("jit(argsort)/sort") for n in sorts) == 1, sorts
+    assert sum("/embedding_remap/" in n for n in sorts) == 1, sorts
+    assert "searchsorted" not in text
